@@ -38,9 +38,11 @@ the explicit shard_map step (training/loop.py make_dist_train_step) on a
   PYTHONPATH=src python -m benchmarks.comm_volume
   PYTHONPATH=src python -m benchmarks.comm_volume --full
 
-The module re-execs itself in a subprocess when jax is already initialized
-with fewer devices (e.g. under benchmarks/run.py), since the forced host
-device count must be set before the first jax import.
+It runs on the CPU only (it sets ``JAX_PLATFORMS=cpu`` before importing
+jax) and refuses a process whose backend is an accelerator.  It re-execs
+itself in a subprocess when jax already started the CPU backend with
+fewer devices (e.g. under benchmarks/run.py), since the forced host
+device count must be set before the backend starts.
 """
 from __future__ import annotations
 
@@ -73,16 +75,15 @@ def _parse(argv):
 
 
 def _measure(body, sds, mesh):
-    """AOT-compile ``shard_map(body)`` on ``mesh`` and return per-chip
+    """AOT-compile ``jax.shard_map(body)`` on ``mesh`` and return per-chip
     collective bytes/counts from the optimized HLO."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch import hlo_analysis
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                       check_vma=False)
     hlo = jax.jit(fn).lower(sds).compile().as_text()
     ana = hlo_analysis.analyze(hlo)
     return {"link_bytes": ana["collective_total_bytes"],
@@ -368,19 +369,25 @@ def _strip_device_flag(flags: str) -> str:
 def main(argv=None) -> None:
     args = _parse(argv if argv is not None else sys.argv[1:])
     need = max(args.devices, DEVICES if args.full else args.devices)
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "jax" not in sys.modules \
-            and "--xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={need} " + flags
+    # A CPU-only tool: it compiles for ``need`` fake host devices and never
+    # takes an accelerator.  A fresh process settles both before JAX starts.
+    if "jax" not in sys.modules:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={need} "
+            + _strip_device_flag(os.environ.get("XLA_FLAGS", "")))
     import jax
+    if jax.default_backend() != "cpu":
+        raise SystemExit("benchmarks.comm_volume compiles for fake CPU "
+                         "devices: run it in its own process with "
+                         "JAX_PLATFORMS=cpu, not beside an accelerator")
     if jax.device_count() < need:
-        # backend already locked at a smaller device count (e.g. under
-        # benchmarks/run.py, or an inherited XLA_FLAGS) — re-exec with the
-        # forced count, replacing any pre-set device-count flag
-        env = dict(os.environ)
+        # this CPU process started its backend with fewer devices (e.g.
+        # under benchmarks/run.py) — re-exec with the forced count,
+        # replacing any pre-set device-count flag
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={need} "
-                            + _strip_device_flag(flags))
+                            + _strip_device_flag(env.get("XLA_FLAGS", "")))
         cmd = [sys.executable, "-m", "benchmarks.comm_volume",
                "--arch", args.arch, "--devices", str(args.devices),
                "--inv-freq", str(args.inv_freq), "--quant", args.quant,

@@ -20,23 +20,26 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 # jaxpr-level collective primitives (lax.psum -> "psum",
-# lax.psum_scatter -> "reduce_scatter", ...).  Under shard_map with
-# check_rep=True jax rewrites psum/pmax/pmin to their "2" variants
-# (psum2 + a pbroadcast marker); they are the same wire traffic, so the
-# walker records them under the unsuffixed name (see _canon_prim).
+# lax.psum_scatter -> "reduce_scatter", ...).  Under jax.shard_map with
+# check_vma=True a psum of device-varying values lowers to
+# "psum_invariant" (plus "pvary" markers on its inputs); older jax wrote
+# "psum2"/"pmax2"/"pmin2" (plus "pbroadcast").  They are the same wire
+# traffic, so the walker records them under the plain name (_canon_prim).
+_RENAMED = {"psum_invariant": "psum", "psum2": "psum", "pmax2": "pmax",
+            "pmin2": "pmin"}
 COLLECTIVE_PRIMS = ("psum", "all_gather", "reduce_scatter", "all_to_all",
                     "ppermute", "pmax", "pmin", "all_gather_invariant",
-                    "psum2", "pmax2", "pmin2")
+                    *_RENAMED)
 
 
 def _canon_prim(name: str) -> str:
-    return name[:-1] if name in ("psum2", "pmax2", "pmin2") else name
+    return _RENAMED.get(name, name)
 
 # primitives that merely re-arrange data; producer-chain walks look
 # through them when tracing a collective payload back to its origin
 _TRANSPARENT = ("reshape", "transpose", "broadcast_in_dim", "squeeze",
                 "slice", "concatenate", "copy", "convert_element_type",
-                "mul", "add", "div", "pbroadcast")
+                "mul", "add", "div", "pbroadcast", "pvary")
 
 
 def _aval_info(v) -> Tuple[Tuple[int, ...], str, int]:

@@ -14,26 +14,6 @@ import argparse
 import os
 import sys
 
-# --dist traces the shard_map step over fake host devices; the device
-# count must be forced before jax initializes (same dance as
-# launch/train.py)
-if "--dist" in sys.argv \
-        and "--xla_force_host_platform_device_count" \
-        not in os.environ.get("XLA_FLAGS", ""):
-    _n = 8
-    for _i, _a in enumerate(sys.argv):
-        try:
-            if _a == "--dist-devices":
-                _n = int(sys.argv[_i + 1])
-            elif _a.startswith("--dist-devices="):
-                _n = int(_a.split("=", 1)[1])
-        except (ValueError, IndexError):
-            pass
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={_n} "
-        + os.environ.get("XLA_FLAGS", ""))
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(
         description=__doc__,
@@ -85,7 +65,14 @@ def main() -> int:
                     help="also write the report as JSON to this path")
     args = ap.parse_args()
 
-    # deferred: these pull in jax, which must see XLA_FLAGS first
+    # A CPU-only tool: pin the CPU, and give --dist its fake host devices,
+    # before the deferred imports below start jax.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if args.dist and "--xla_force_host_platform_device_count" \
+            not in os.environ.get("XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={args.dist_devices} "
+            + os.environ.get("XLA_FLAGS", ""))
     import dataclasses
 
     from repro.analysis import trace

@@ -276,7 +276,9 @@ def _fused_block_smw_kernel(j_ref, vr_ref, vc_ref, gm_ref,
                 minv = jnp.where(rows == kk, mrow, minv)
             m_ref[...] = minv
             if with_pivot:
-                piv_ref[0, 0] = pmin
+                # a (1, 1) vector store: Mosaic refuses scalar stores
+                # into a VMEM block
+                piv_ref[...] = jnp.broadcast_to(pmin, (1, 1))
 
         ui = u_ref[pl.ds(i * block, block), :]
         uk = u_ref[pl.ds(k * block, block), :]

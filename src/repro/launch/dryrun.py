@@ -1,9 +1,11 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            "--xla_allow_excess_precision=false")
-# The two lines above MUST run before any jax import: jax locks the device
-# count on first init, and the production-mesh dry-run needs 512 host
-# placeholder devices (2 pods x 16 x 16).  Everything below is ordinary.
+# The lines above MUST run before any jax import: the dry-run is a CPU-only
+# tool that never takes an accelerator, jax locks the device count on
+# first init, and the production-mesh dry-run needs 512 host placeholder
+# devices (2 pods x 16 x 16).  Everything below is ordinary.
 """Multi-pod dry-run: AOT-lower + compile every (architecture x input-shape
 x mesh) combination against the production mesh, and extract the roofline
 inputs (FLOPs, bytes, collective traffic, per-device memory) from the

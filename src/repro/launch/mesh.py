@@ -28,27 +28,40 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return jax.make_mesh(shape, axes, devices=devices[:n])
 
 
+def request_host_devices(n: int) -> None:
+    """Ask the CPU backend for ``n`` fake devices, for multi-device paths
+    on the host.  Only the CPU platform reads the setting, and only when
+    it starts: once a backend is up, its device count stands."""
+    try:
+        jax.config.update("jax_num_cpu_devices", n)
+    except RuntimeError:                # a backend has already started
+        pass
+
+
 def make_host_mesh(n_data: int = 1, *, n_model: int = 1,
                    n_pod: int = 0) -> Mesh:
-    """Host-platform mesh for CPU tests / examples / ``train.py --dist``.
+    """Mesh over the first devices of the default backend, for the chips
+    of one host (``train.py --dist``) or CPU tests and examples.
 
-    The default (1, 1) runs on the single real device.  Multi-device
-    variants need fake host devices: set
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` *before* jax
-    initializes (tests/conftest.py pins 8; launch/train.py sets it when
-    ``--dist`` is passed).  ``n_pod > 0`` builds the multi-pod
-    ("pod", "data", "model") axes so the ("pod", "data") FSDP/collective
-    paths are exercisable on CPU.
+    The default (1, 1) runs on a single device.  On the CPU, multi-device
+    variants need fake host devices, asked for before jax starts its
+    backend (:func:`request_host_devices`, or ``XLA_FLAGS=
+    --xla_force_host_platform_device_count=N`` as tests/conftest.py
+    pins 8).  ``n_pod > 0`` builds the multi-pod ("pod", "data",
+    "model") axes so the ("pod", "data") FSDP/collective paths are
+    exercisable on CPU.
     """
     shape = ((n_pod,) if n_pod else ()) + (n_data, n_model)
     axes = (("pod",) if n_pod else ()) + ("data", "model")
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) < n:
-        raise RuntimeError(
-            f"host mesh {shape} needs {n} devices, have {len(devices)} — "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{n} before the first jax import")
+        platform = devices[0].platform
+        hint = (" — ask for fake host devices before jax starts "
+                "(launch/mesh.request_host_devices)"
+                if platform == "cpu" else "")
+        raise RuntimeError(f"host mesh {shape} needs {n} devices, have "
+                           f"{len(devices)} {platform} device(s){hint}")
     return jax.make_mesh(shape, axes, devices=devices[:n])
 
 

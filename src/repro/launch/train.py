@@ -1,41 +1,22 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-Runs a real (CPU-scale) training job on a reduced or full config with any
-of the implemented optimizers, checkpointing and logging included.  On a
-real TPU slice the same entry point runs the full config under the
-production mesh (the sharding rules are mesh-size agnostic); in this
-container it is exercised with ``--reduced`` (the per-arch smoke scale).
+Runs a training job on a reduced or full config with any of the
+implemented optimizers, checkpointing and logging included.  On a TPU it
+trains the full config (``chip_smoke.py`` drives bert-large at full width
+through :func:`main`); on the CPU (``JAX_PLATFORMS=cpu``) the Pallas
+kernels run in interpret mode, ``--dist`` runs over fake host devices,
+and ``--reduced`` gives the per-arch smoke scale.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
-import sys
 import time
-
-# --dist runs the explicit-collective shard_map step (DESIGN.md §10) over
-# fake host devices when no accelerator slice is attached.  The device
-# count must be forced before jax initializes, so peek at argv here; the
-# flag only affects the host platform (a real TPU backend ignores it).
-if "--dist" in sys.argv \
-        and "--xla_force_host_platform_device_count" \
-        not in os.environ.get("XLA_FLAGS", ""):
-    _n = 8
-    for _i, _a in enumerate(sys.argv):
-        try:
-            if _a == "--dist-devices":          # space-separated form
-                _n = int(sys.argv[_i + 1])
-            elif _a.startswith("--dist-devices="):
-                _n = int(_a.split("=", 1)[1])
-        except (ValueError, IndexError):
-            pass                                # argparse reports it below
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={_n} "
-        + os.environ.get("XLA_FLAGS", ""))
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import checkpointing
 from repro.configs import registry
@@ -43,11 +24,17 @@ from repro.core import firstorder, schedule as sched_lib
 from repro.core.mkor import MKORConfig, mkor, mkor_h
 from repro.core.eva import EvaConfig, eva
 from repro.data import pipeline
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.models import model as model_lib
 from repro.sharding import collectives
 from repro.sharding import rules
 from repro.training import loop as train_lib
+
+
+# --dist world size on the CPU, where the devices are fake (tests pin the
+# same count in tests/conftest.py)
+HOST_DIST_DEVICES = 8
 
 
 def build_optimizer(name: str, lr, *, inv_freq: int = 10, rank: int = 1,
@@ -61,11 +48,20 @@ def build_optimizer(name: str, lr, *, inv_freq: int = 10, rank: int = 1,
     owner-sharded inversions over the survivors; the state tree is
     mask-independent, so the carried opt state transfers unchanged."""
     # Pallas interpret mode is a testing device, not an execution strategy:
-    # only a real TPU runs the compiled kernels (they use TPU memory
-    # spaces), every other backend interprets.  Before this gate,
-    # --use-pallas on a TPU silently ran the interpreter.
+    # a TPU runs the compiled kernels, the CPU interprets them, and any
+    # other backend is refused.  The CPU must have been asked for
+    # (JAX_PLATFORMS=cpu): JAX falls back to it when a TPU fails to start,
+    # and such a run must not pass for one with the kernels on the chip.
     platform = platform or jax.default_backend()
-    interpret = use_pallas and platform != "tpu"
+    interpret = use_pallas and platform == "cpu"
+    if use_pallas and platform not in ("tpu", "cpu"):
+        raise SystemExit(f"--use-pallas: the kernels need a TPU, or the "
+                         f"CPU in interpret mode; the backend is {platform}")
+    if interpret and "cpu" not in (jax.config.jax_platforms or ""):
+        raise SystemExit("--use-pallas on the CPU runs the kernels in "
+                         "interpret mode, and only when JAX_PLATFORMS=cpu "
+                         "asks for it: without that setting the CPU is "
+                         "what JAX falls back to when the TPU fails")
     backend = firstorder.lamb(lr)
     if name == "mkor":
         mcfg = MKORConfig(
@@ -89,6 +85,17 @@ def build_optimizer(name: str, lr, *, inv_freq: int = 10, rank: int = 1,
     raise ValueError(name)
 
 
+def resolve_dist_devices(requested) -> int:
+    """The --dist world size: every device of an accelerator by default,
+    HOST_DIST_DEVICES fake devices on the CPU; never more than exist."""
+    platform, have = jax.default_backend(), jax.device_count()
+    n = requested or (HOST_DIST_DEVICES if platform == "cpu" else have)
+    if n > have:
+        raise SystemExit(f"--dist-devices {n}: only {have} {platform} "
+                         f"device(s) are present")
+    return n
+
+
 def build_schedule(kind: str, peak: float, steps: int):
     if kind == "constant":
         return sched_lib.constant(peak)
@@ -102,7 +109,9 @@ def build_schedule(kind: str, peak: float, steps: int):
     raise ValueError(kind)
 
 
-def main() -> None:
+def main(argv=None) -> list:
+    """Runs one training job; returns the logged metrics history (one dict
+    per logged step).  ``argv`` defaults to the command line."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--optimizer", default="mkor",
@@ -134,10 +143,12 @@ def main() -> None:
     ap.add_argument("--dist", action="store_true",
                     help="explicit-collective shard_map data-parallel step "
                          "with owner-sharded MKOR inversions (DESIGN.md "
-                         "§10); on CPU this forces fake host devices")
-    ap.add_argument("--dist-devices", type=int, default=8,
-                    help="data-parallel world size for --dist "
-                         "(--global-batch must be a multiple of it)")
+                         "§10); on the CPU it runs over fake host devices")
+    ap.add_argument("--dist-devices", type=int, default=None,
+                    help="data-parallel world size for --dist (default: "
+                         "every device of an accelerator, 8 fake devices "
+                         "on the CPU; --global-batch must be a multiple "
+                         "of it)")
     ap.add_argument("--quant", default="none",
                     choices=["none", "bf16", "int8"],
                     help="factor residency format (DESIGN.md \u00a716): "
@@ -174,8 +185,12 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--log-json", default="")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    if args.dist:
+        # must precede the backend's start; only the CPU reads it
+        mesh_lib.request_host_devices(args.dist_devices or HOST_DIST_DEVICES)
+    compile_cache.enable()
     cfg = registry.get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -183,6 +198,7 @@ def main() -> None:
     lr = build_schedule(args.schedule, args.lr, args.steps)
     mesh = dist = None
     if args.dist:
+        args.dist_devices = resolve_dist_devices(args.dist_devices)
         if args.global_batch % args.dist_devices:
             raise SystemExit(
                 f"--global-batch {args.global_batch} must be a multiple "
@@ -266,6 +282,12 @@ def main() -> None:
                         f"{args.dist_devices if args.dist else 1}")
             print(f"restored checkpoint step {latest} "
                   f"(data cursor {start}{note})")
+    if args.dist:
+        # Commit the state to the mesh before the first chunk: the runner
+        # returns it replicated, and state left on one device would make
+        # the second chunk compile the runner a second time.
+        params, opt_state = jax.device_put((params, opt_state),
+                                           NamedSharding(mesh, P()))
 
     def make_batch(step: int):
         batch = pipeline.make_batch(ds, step)
@@ -340,11 +362,12 @@ def main() -> None:
             json.dump(history, f, indent=1)
     if preempted:
         print("preempted: emergency checkpoint taken, exiting cleanly")
-        return
+        return history
     final = history[-1]["loss"] if history else float("nan")
     print(f"done: final loss {final:.4f}")
     if not np.isfinite(final):
         raise SystemExit("training diverged")
+    return history
 
 
 if __name__ == "__main__":

@@ -5,7 +5,7 @@ workers exchange the rank-1 statistics vectors ā (d_in,) and ḡ (d_out,) —
 O(d) on the wire — instead of the O(d²) Kronecker factors/inverses that
 KFAC/KAISA-style distributions broadcast on every factor update.  This
 module is the communication layer that makes that schedule explicit under
-``jax.experimental.shard_map`` instead of leaving collective placement to
+``jax.shard_map`` instead of leaving collective placement to
 GSPMD:
 
 * :func:`pmean_rank1_stats` — mean-reduce only the rank-1 ``"a"`` leaves of

@@ -14,7 +14,6 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import firstorder
@@ -203,9 +202,9 @@ def make_dist_step_fn(grads_fn: Callable, optimizer: GradientTransformation,
                     f"data world size {world}")
         bspecs = jax.tree.map(
             lambda x: P(batch_axis, *([None] * (x.ndim - 1))), batch)
-        fn = shard_map(local_step, mesh=mesh,
-                       in_specs=(P(), P(), bspecs),
-                       out_specs=(P(), P(), P()), check_rep=False)
+        fn = jax.shard_map(local_step, mesh=mesh,
+                           in_specs=(P(), P(), bspecs),
+                           out_specs=(P(), P(), P()), check_vma=False)
         return fn(params, opt_state, batch)
 
     return jax.jit(step)

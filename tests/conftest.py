@@ -1,5 +1,11 @@
 import os
 
+# The suite runs on the CPU (Pallas kernels in interpret mode).  Pin it
+# before jax starts, so that no test process takes an accelerator that a
+# chip run may need; the kernels' TPU compiles (tests/test_tpu_compile.py)
+# target a described chip and need none.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 # Multi-device paths (sharding/collectives.py, training/loop.py dist step)
 # are tested on 8 fake CPU devices via launch/mesh.make_host_mesh(n_data=..)
 # — the flag must be set before jax initializes, and the backend is locked

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis import jaxpr_walk, trace
@@ -71,7 +70,7 @@ def test_seeded_factor_payload_trips_comm_lint():
     mesh = Mesh(np.array(jax.devices()[:8]), ("d",))
 
     def bad_step(x):
-        return shard_map.shard_map(
+        return jax.shard_map(
             lambda v: jax.lax.psum(v, "d"),
             mesh=mesh, in_specs=P(), out_specs=P())(x)
 
@@ -96,7 +95,7 @@ def test_seeded_collective_count_drift_trips_comm_lint():
         def inner(xs):
             # per-leaf psums — the drift the bucketed design removed
             return [jax.lax.psum(x, "d") for x in xs]
-        return shard_map.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(), out_specs=P())(xs)
 
     xs = [jax.ShapeDtypeStruct((16,), jnp.float32)] * 12
@@ -113,7 +112,7 @@ def test_seeded_collective_count_drift_trips_comm_lint():
 # Seeded violation 2: float64 promotion (dtype-discipline)
 # --------------------------------------------------------------------- #
 def test_seeded_f64_promotion_trips_dtype_lint():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: jnp.sum(x.astype(jnp.float64) * 2.0))(
             jax.ShapeDtypeStruct((8, 8), jnp.float32))
@@ -211,7 +210,7 @@ def test_seeded_ungated_factor_gather_trips_staleness_lint():
             synced = jax.lax.psum(p, "d")                  # ungated O(d^2)
             return jax.lax.cond(True, lambda x: x,
                                 lambda x: x, synced)
-        return shard_map.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(), out_specs=P())(pending)
 
     target = trace.custom_target(
@@ -234,7 +233,7 @@ def test_seeded_extra_step_bytes_trips_staleness_lint():
     def chatty_tick(v):
         def inner(x):
             return jax.lax.psum(x, "d")   # 1 MB of new every-step traffic
-        return shard_map.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(), out_specs=P())(v)
 
     target = trace.custom_target(
@@ -268,7 +267,7 @@ def test_seeded_health_factor_broadcast_trips_health_lint():
     def leaky_reset(bank):
         def inner(b):
             return jax.lax.psum(b, "d")                    # ungated O(d^2)
-        return shard_map.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(), out_specs=P())(bank)
 
     target = trace.custom_target(
@@ -292,7 +291,7 @@ def test_seeded_health_extra_collective_trips_health_lint():
     def agreeing_step(flags):
         def inner(f):
             return jax.lax.psum(f, "d")    # cross-worker trip agreement
-        return shard_map.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(), out_specs=P())(flags)
 
     target = trace.custom_target(
@@ -411,7 +410,7 @@ def test_seeded_remap_factor_broadcast_trips_elastic_lint():
     def rebroadcast(bank):
         def inner(b):
             return jax.lax.psum(b, "d")                    # ungated O(d^2)
-        return shard_map.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(), out_specs=P())(bank)
 
     target = trace.custom_target(
@@ -436,7 +435,7 @@ def test_seeded_remap_extra_collective_trips_elastic_lint():
     def liveness_round(flags):
         def inner(f):
             return jax.lax.psum(f, "d")    # cross-worker liveness vote
-        return shard_map.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(), out_specs=P())(flags)
 
     args = (jax.ShapeDtypeStruct((16,), jnp.float32),)
@@ -471,7 +470,7 @@ def test_seeded_dequantized_wire_trips_quant_lint():
             return jax.lax.cond(jnp.sum(bank) > 0,
                                 lambda b: jax.lax.psum(b * 0.0, "d") + b,
                                 lambda b: b, bank)     # ...on the wire
-        return shard_map.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(), out_specs=P())(codes)
 
     target = trace.custom_target(
@@ -497,7 +496,7 @@ def test_seeded_bf16_accum_trips_quant_lint():
                                 lambda c: jax.lax.psum(
                                     c.astype(jnp.bfloat16), "d"),
                                 lambda c: c.astype(jnp.bfloat16), q)
-        return shard_map.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(), out_specs=P())(codes)
 
     target = trace.custom_target(
@@ -518,7 +517,7 @@ def test_seeded_bf16_accum_trips_quant_lint():
             return jax.lax.cond(jnp.sum(q) > 0,
                                 lambda c: jax.lax.psum(c, "d"),
                                 lambda c: c, q)
-        return shard_map.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(), out_specs=P())(codes)
 
     good = trace.custom_target(

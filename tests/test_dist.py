@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import checkpointing
@@ -81,9 +80,9 @@ def test_flat_all_reduce_matches_psum_mean(rng):
             lambda x: jax.lax.pmean(x, "data"), t)
         return got, want
 
-    got, want = jax.jit(shard_map(
+    got, want = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
-        check_rep=False))(tree)
+        check_vma=False))(tree)
     _assert_trees_close(got, want, rtol=1e-6, atol=1e-7)
 
 
@@ -100,9 +99,9 @@ def test_pmean_rank1_stats_reduces_a_and_drops_full_stats(rng):
         return collectives.pmean_rank1_stats(local, dist,
                                              payload_dtype=None)
 
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
-        check_rep=False))(stats)
+        check_vma=False))(stats)
     node = out["layers"][0]
     assert set(node) == {"a"}                 # O(d) contract: means only
     np.testing.assert_allclose(np.asarray(node["a"]),
@@ -121,8 +120,8 @@ def test_owner_shard_gather_roundtrip_is_identity():
             mine = collectives.owner_shard(v, dist)
             return collectives.gather_shards(2.0 * mine, dist, v.shape[0])
 
-        out = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),),
-                                out_specs=P(), check_rep=False))(x)
+        out = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                    out_specs=P(), check_vma=False))(x)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(2.0 * x))
 
 
@@ -463,8 +462,8 @@ def test_owner_shard_gather_roundtrip_with_dead_worker():
             return collectives.gather_shards(2.0 * mine, dist,
                                              v.shape[0], live=live)
 
-        out = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),),
-                                out_specs=P(), check_rep=False))(x)
+        out = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                    out_specs=P(), check_vma=False))(x)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(2.0 * x))
 
 

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import checkpointing
@@ -284,8 +283,8 @@ def test_owner_gather_quant_recombines_exactly(rng, n):
         return collectives.owner_sharded_map_quant(
             statlib.quant_encode, [xx], dist, n)
 
-    q, sc = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),),
-                              out_specs=P(), check_rep=False))(x)
+    q, sc = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                  out_specs=P(), check_vma=False))(x)
     q_ref, sc_ref = statlib.quant_encode(x)
     assert q.dtype == jnp.dtype(collectives.QUANT_WIRE_DTYPE)
     np.testing.assert_array_equal(np.asarray(q)[:n], np.asarray(q_ref))
@@ -305,8 +304,8 @@ def test_owner_gather_quant_rejects_wide_codes(rng):
             [xx], dist, 8)
 
     with pytest.raises(TypeError, match="int8"):
-        jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),),
-                          out_specs=P(), check_rep=False))(x)
+        jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                              out_specs=P(), check_vma=False))(x)
 
 
 # --------------------------------------------------------------------- #
