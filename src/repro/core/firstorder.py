@@ -7,6 +7,7 @@ for CNNs; both are implemented here, plus Adam/AdamW for completeness and a
 
 Convention: ``update`` returns *additive* updates — apply with
 ``params = tree_add(params, updates)`` (updates already contain the -lr).
+Each ``update`` runs under the ``backend`` named scope (repro/scopes.py).
 """
 from __future__ import annotations
 
@@ -14,6 +15,8 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro import scopes
 
 Params = Any
 State = Any
@@ -73,6 +76,7 @@ def sgd(lr, momentum: float = 0.0, nesterov: bool = False,
         return {"count": jnp.zeros((), jnp.int32),
                 "mu": _tree_zeros(params) if momentum else None}
 
+    @scopes.scoped(scopes.BACKEND)
     def update(grads, state, params=None, **_):
         step = state["count"]
         if weight_decay and params is not None:
@@ -116,6 +120,7 @@ def adam(lr, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
         return {"count": jnp.zeros((), jnp.int32),
                 "m": _tree_zeros(params), "v": _tree_zeros(params)}
 
+    @scopes.scoped(scopes.BACKEND)
     def update(grads, state, params=None, **_):
         step = state["count"] + 1
         m, v = _adam_moments(grads, state, b1, b2)
@@ -152,6 +157,7 @@ def lamb(lr, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-6,
         return {"count": jnp.zeros((), jnp.int32),
                 "m": _tree_zeros(params), "v": _tree_zeros(params)}
 
+    @scopes.scoped(scopes.BACKEND)
     def update(grads, state, params=None, **_):
         assert params is not None, "lamb needs params (trust ratio)"
         step = state["count"] + 1
@@ -182,6 +188,7 @@ def clip_by_global_norm(max_norm: float) -> GradientTransformation:
     def init(params):
         return {}
 
+    @scopes.scoped(scopes.BACKEND)
     def update(grads, state, params=None, **_):
         gn = global_norm(grads)
         scale = jnp.minimum(1.0, max_norm / jnp.maximum(gn, 1e-12))

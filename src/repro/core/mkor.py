@@ -88,6 +88,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.core import stats as statlib
 from repro.core.firstorder import GradientTransformation
 from repro.sharding import collectives
@@ -353,6 +354,7 @@ def _vmap_over_stack(fn, n_stack: int):
 # reductions of already-materialized data — no collectives, so under dist
 # every worker derives the identical signals from its replicated copies.
 # ----------------------------------------------------------------------- #
+@scopes.scoped(scopes.MKOR_SMW)
 def _any_nonfinite(arrays) -> jnp.ndarray:
     """Scalar bool: any non-finite element anywhere in ``arrays``."""
     bad = jnp.zeros((), jnp.bool_)
@@ -361,6 +363,7 @@ def _any_nonfinite(arrays) -> jnp.ndarray:
     return bad
 
 
+@scopes.scoped(scopes.MKOR_SMW)
 def _finite_or_zero(x: jnp.ndarray) -> jnp.ndarray:
     """Replace non-finite elements with 0 (identity on clean data)."""
     return jnp.where(jnp.isfinite(x), x, jnp.zeros((), x.dtype))
@@ -371,6 +374,7 @@ def _slice_sumsq(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(jnp.square(x.astype(jnp.float32)), axis=(-2, -1))
 
 
+@scopes.scoped(scopes.MKOR_SMW)
 def _identity_like(bank: jnp.ndarray) -> jnp.ndarray:
     """Identity factors broadcast to a bank's shape — the quarantine
     reset value.  An identity bank preconditions to ΔW = I·G·I = G and
@@ -406,6 +410,7 @@ def _quant_identity_side(shape: Tuple[int, ...], d: int):
             jnp.zeros(shape + (d, d), jnp.float32))
 
 
+@scopes.scoped(scopes.MKOR_SMW)
 def _quant_side_reset(side, trip):
     """Quarantine reset of a quantized side: identity codes + identity
     scale + ZERO error feedback — a stale residual from before the trip
@@ -529,26 +534,35 @@ def mkor(backend: GradientTransformation,
     needs_window = cfg.rank > 1 or cfg.staleness > 0
     win_rank = max(cfg.rank, 1)
 
+    # Each stage runs under its named scope (repro/scopes.py), set on the
+    # closures every path shares (bank, per-layer, async, int8): stabilize,
+    # the factor updates and the health signals under mkor_smw, the
+    # precondition under mkor_precondition.
     if cfg.use_pallas:
         from repro.kernels import ops as kops
-        smw_fn = partial(kops.smw_rank1_update, gamma=cfg.gamma,
-                         variant=cfg.variant, interpret=cfg.interpret)
+        smw_fn = scopes.scoped(scopes.MKOR_SMW)(partial(
+            kops.smw_rank1_update, gamma=cfg.gamma, variant=cfg.variant,
+            interpret=cfg.interpret))
 
+        @scopes.scoped(scopes.MKOR_SMW)
         def banked_smw(j, v, n_lead):
             return kops.smw_rank1_update_banked(
                 j, v, gamma=cfg.gamma, variant=cfg.variant,
                 interpret=cfg.interpret)
 
+        @scopes.scoped(scopes.MKOR_SMW)
         def block_slice(j, v, n):
             return kops.smw_block_update(
                 j, v, gamma=cfg.gamma, variant=cfg.variant, n_valid=n,
                 interpret=cfg.interpret)
 
+        @scopes.scoped(scopes.MKOR_SMW)
         def banked_block(j, v, n, n_lead):
             return kops.smw_block_update_banked(
                 j, v, n, gamma=cfg.gamma, variant=cfg.variant,
                 interpret=cfg.interpret)
 
+        @scopes.scoped(scopes.MKOR_SMW)
         def banked_block_piv(j, v, n, n_lead):
             # (new bank, min GJ pivot) — the pivot comes straight from
             # the fused kernel's in-register elimination
@@ -556,6 +570,7 @@ def mkor(backend: GradientTransformation,
                 j, v, n, gamma=cfg.gamma, variant=cfg.variant,
                 interpret=cfg.interpret, with_pivot=True)
 
+        @scopes.scoped(scopes.MKOR_PRECONDITION)
         def precond_slice(linv, rinv, gw):
             # fused precondition + Frobenius rescale, one dispatch per
             # slice (kernels/precond.py; extra dims / VMEM overflow fall
@@ -565,23 +580,28 @@ def mkor(backend: GradientTransformation,
                                             interpret=cfg.interpret)
             return delta.astype(gw.dtype)
 
+        @scopes.scoped(scopes.MKOR_PRECONDITION)
         def banked_precond(l, r, gw, n_lead):
             delta = kops.fused_precondition_banked(
                 l, r, gw, rescale=cfg.rescale, interpret=cfg.interpret)
             return delta.astype(gw.dtype)
     else:
-        smw_fn = partial(smw_update_maybe_rank_r, gamma=cfg.gamma,
-                         variant=cfg.variant)
+        smw_fn = scopes.scoped(scopes.MKOR_SMW)(partial(
+            smw_update_maybe_rank_r, gamma=cfg.gamma, variant=cfg.variant))
 
+        @scopes.scoped(scopes.MKOR_SMW)
         def banked_smw(j, v, n_lead):
             return _vmap_over_stack(smw_fn, n_lead)(j, v)
 
+        @scopes.scoped(scopes.MKOR_SMW)
         def block_slice(j, v, n):
             return smw_block_update(j, v, cfg.gamma, cfg.variant, n_valid=n)
 
+        @scopes.scoped(scopes.MKOR_SMW)
         def banked_block(j, v, n, n_lead):
             return _vmap_over_stack(block_slice, n_lead)(j, v, n)
 
+        @scopes.scoped(scopes.MKOR_SMW)
         def banked_block_piv(j, v, n, n_lead):
             out, piv = _vmap_over_stack(
                 lambda jj, vv, nn: smw_block_update(
@@ -589,18 +609,21 @@ def mkor(backend: GradientTransformation,
                     with_pivot=True), n_lead)(j, v, n)
             return out, jnp.min(piv)
 
+        @scopes.scoped(scopes.MKOR_PRECONDITION)
         def precond_slice(linv, rinv, gw):
             delta = precondition(linv, rinv, gw)
             if cfg.rescale:
                 delta = rescale_update(delta, gw)
             return delta.astype(gw.dtype)
 
+        @scopes.scoped(scopes.MKOR_PRECONDITION)
         def banked_precond(l, r, gw, n_lead):
             return _vmap_over_stack(precond_slice, n_lead)(l, r, gw)
 
-    stab_slice = partial(stabilize, threshold=cfg.stabilizer_threshold,
-                         zeta=cfg.zeta)
+    stab_slice = scopes.scoped(scopes.MKOR_SMW)(partial(
+        stabilize, threshold=cfg.stabilizer_threshold, zeta=cfg.zeta))
 
+    @scopes.scoped(scopes.MKOR_SMW)
     def norm_hot(bank):
         # ‖F⁻¹‖∞ trend signal (DESIGN.md §14): the stabilizer caps the
         # norm AT the threshold every inversion, so a bank sitting well
@@ -645,6 +668,7 @@ def mkor(backend: GradientTransformation,
             return ((bank["l_inv"], bank["l_scale"], bank["l_ef"]),
                     (bank["r_inv"], bank["r_scale"], bank["r_ef"]))
 
+        @scopes.scoped(scopes.MKOR_SMW)
         def side_rank1(side, v, ns1):
             """stab∘SMW on one quantized side (rank-1 schedule)."""
             q, sc, ef = side
@@ -678,6 +702,7 @@ def mkor(backend: GradientTransformation,
                 cfg.dist, n, cfg.live)
             return (qg.reshape(q.shape), scg.reshape(sc.shape), ef)
 
+        @scopes.scoped(scopes.MKOR_SMW)
         def side_block(side, v_ord, cnt_full, ns1, want_pivot):
             """Block-Woodbury + stab + requant on one quantized side.
             Returns (new side, min GJ pivot); pivot is +inf when the
@@ -723,6 +748,7 @@ def mkor(backend: GradientTransformation,
                 cfg.dist, n, cfg.live)
             return (qg.reshape(q.shape), scg.reshape(sc.shape), ef), piv
 
+        @scopes.scoped(scopes.MKOR_PRECONDITION)
         def side_precond(l_side, r_side, gw, ns1):
             lq, lsc, _ = l_side
             rq, rsc, _ = r_side
@@ -741,11 +767,22 @@ def mkor(backend: GradientTransformation,
             # the fp32 scale + error-feedback leaves instead
             return [side[1], side[2]]
 
+        @scopes.scoped(scopes.MKOR_SMW)
         def sides_bad(l_side, r_side):
             return (_any_nonfinite(side_finite_srcs(l_side)
                                    + side_finite_srcs(r_side))
                     | (_quant_side_maxabs(l_side) > hot_norm)
                     | (_quant_side_maxabs(r_side) > hot_norm))
+
+    @scopes.scoped(scopes.MKOR_PRECONDITION)
+    def put_deltas(out, bucket, delta, gw, so_on):
+        """Write a bucket's preconditioned slices into the update tree;
+        the raw gradients where MKOR-H has switched MKOR off."""
+        delta = jnp.where(so_on, delta, gw)
+        for i, path in enumerate(bucket.paths):
+            out = statlib.tree_set(
+                out, path, {**statlib.tree_get(out, path), "w": delta[i]})
+        return out
 
     # ------------------------------------------------------------------ #
     # init
@@ -1228,7 +1265,8 @@ def mkor(backend: GradientTransformation,
             # below tolerance).  A trip resets the bucket's banks to
             # identity — exact first-order passthrough — before they are
             # consumed or stored. ---------------------------------------- #
-            gw = jnp.stack(g_ws)
+            with jax.named_scope(scopes.MKOR_PRECONDITION):
+                gw = jnp.stack(g_ws)
             if cfg.health:
                 if quant8:
                     post_bad = (_any_nonfinite(side_finite_srcs(l_side)
@@ -1307,11 +1345,7 @@ def mkor(backend: GradientTransformation,
                 if quant8:
                     w["a_scale"], w["g_scale"] = a_wsc, g_wsc
                 new_windows[bucket.bucket_id] = w
-            delta = jnp.where(so_on, delta, gw_c)     # MKOR-H fallback
-            for i, path in enumerate(bucket.paths):
-                out = statlib.tree_set(
-                    out, path,
-                    {**statlib.tree_get(out, path), "w": delta[i]})
+            out = put_deltas(out, bucket, delta, gw_c, so_on)
         fstate = {"factor_banks": new_banks}
         if cfg.rank > 1:
             fstate["stat_windows"] = new_windows
@@ -1629,7 +1663,8 @@ def mkor(backend: GradientTransformation,
                     if quant8:
                         a_wsc = a_wsc.at[idx].set(awsc)
                         g_wsc = g_wsc.at[idx].set(gwsc)
-            stacked_gw = jnp.stack(g_ws)
+            with jax.named_scope(scopes.MKOR_PRECONDITION):
+                stacked_gw = jnp.stack(g_ws)
             if cfg.health:
                 if quant8:
                     l_act_s = _quant_side_reset(l_act_s, trip)
@@ -1687,11 +1722,7 @@ def mkor(backend: GradientTransformation,
             if quant8:
                 w["a_scale"], w["g_scale"] = a_wsc, g_wsc
             new_windows[bucket.bucket_id] = w
-            delta = jnp.where(so_on, delta, gw_c)     # MKOR-H fallback
-            for i, path in enumerate(bucket.paths):
-                out = statlib.tree_set(
-                    out, path,
-                    {**statlib.tree_get(out, path), "w": delta[i]})
+            out = put_deltas(out, bucket, delta, gw_c, so_on)
         fstate = {"factor_banks": new_banks if cfg.health
                   else state["factor_banks"],
                   "pending_banks": new_pending if cfg.health

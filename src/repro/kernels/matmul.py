@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import scopes
+
 DEFAULT_BLOCK = 256
 
 
@@ -53,4 +55,5 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name=scopes.MATMUL_KERNEL,
     )(a, b)

@@ -42,6 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import scopes
+
 DEFAULT_BLOCK = 256
 RESCALE_EPS = 1e-30          # same guard as core.mkor.rescale_update
 
@@ -153,4 +155,5 @@ def fused_precond(r_inv: jnp.ndarray, g: jnp.ndarray, l_inv: jnp.ndarray, *,
                         pltpu.SMEM((1, 1), jnp.float32),
                         pltpu.SMEM((1, 1), jnp.float32)],
         interpret=interpret,
+        name=scopes.PRECOND_KERNEL,
     )(*operands)

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import scopes
+
 DEFAULT_BLOCK = 256
 
 
@@ -351,6 +353,7 @@ def fused_block_smw(j: jnp.ndarray, vt: jnp.ndarray, gm: jnp.ndarray, *,
                         pltpu.VMEM((r, r), jnp.float32),
                         pltpu.VMEM((r, r), jnp.float32)],
         interpret=interpret,
+        name=scopes.BLOCK_SMW_KERNEL,
     )(*operands)
 
 
@@ -391,4 +394,5 @@ def fused_smw(j: jnp.ndarray, v: jnp.ndarray, *, gamma: float,
         scratch_shapes=[pltpu.VMEM((d, 1), jnp.float32),
                         pltpu.SMEM((1, 1), jnp.float32)],
         interpret=interpret,
+        name=scopes.SMW_KERNEL,
     )(*operands)

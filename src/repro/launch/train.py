@@ -35,6 +35,9 @@ from repro.training import loop as train_lib
 # --dist world size on the CPU, where the devices are fake (tests pin the
 # same count in tests/conftest.py)
 HOST_DIST_DEVICES = 8
+# --profile-dir traces the chunks after this many: the first compiles the
+# runner, and the trace should hold the steady state only
+PROFILE_AFTER = 2
 
 
 def build_optimizer(name: str, lr, *, inv_freq: int = 10, rank: int = 1,
@@ -185,7 +188,17 @@ def main(argv=None) -> list:
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--log-json", default="")
+    ap.add_argument("--profile-dir", default="",
+                    help="write a profiler trace (.xplane.pb, for xprof or "
+                         "TensorBoard) of --profile-chunks chunks, after "
+                         "the first two, under this directory")
+    ap.add_argument("--profile-chunks", type=int, default=2)
     args = ap.parse_args(argv)
+    if args.profile_dir and args.elastic:
+        raise SystemExit("--profile-dir traces the plain chunk loop, not "
+                         "the --elastic one")
+    if args.profile_chunks < 1:
+        raise SystemExit("--profile-chunks must be at least 1")
 
     if args.dist:
         # must precede the backend's start; only the CPU reads it
@@ -341,11 +354,22 @@ def main(argv=None) -> list:
         # at most two distinct chunk lengths (full + one trailing
         # partial), so the runner compiles at most two traces
         # (train_lib.chunk_schedule)
-        for n in train_lib.chunk_schedule(args.steps - start, args.chunk):
-            stacked = train_lib.stack_batches([make_batch(i + k)
-                                               for k in range(n)])
-            params, opt_state, metrics = runner(params, opt_state, stacked)
-            metrics = jax.device_get(metrics)
+        chunks = train_lib.chunk_schedule(args.steps - start, args.chunk)
+        first = PROFILE_AFTER
+        last = min(first + args.profile_chunks, len(chunks)) - 1
+        if args.profile_dir and last < first:
+            raise SystemExit(f"--profile-dir: the run has {len(chunks)} "
+                             f"chunk(s); the trace starts after {first}")
+        for c, n in enumerate(chunks):
+            if args.profile_dir and c == first:
+                jax.profiler.start_trace(args.profile_dir)
+            params, opt_state, metrics = train_lib.run_chunk(
+                runner, params, opt_state,
+                [make_batch(i + k) for k in range(n)], i)
+            if args.profile_dir and c == last:
+                jax.profiler.stop_trace()
+                print(f"profile: chunks {first}-{last} traced under "
+                      f"{args.profile_dir}")
             for k in range(n):
                 log_step(i + k,
                          {key: float(v[k]) for key, v in metrics.items()})

@@ -28,6 +28,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
+
 Params = Dict[str, jnp.ndarray]
 
 
@@ -57,8 +59,9 @@ def dense(p: Params, x: jnp.ndarray, *, stats: Optional[dict] = None,
           name: str = "") -> jnp.ndarray:
     """y = x @ W (+ b) + probe, recording E[a] into ``stats[name]``."""
     if stats is not None:
-        flat = x.reshape(-1, x.shape[-1])
-        stats[name] = {"a": jnp.mean(flat.astype(jnp.float32), axis=0)}
+        with jax.named_scope(scopes.MKOR_STATS):
+            flat = x.reshape(-1, x.shape[-1])
+            stats[name] = {"a": jnp.mean(flat.astype(jnp.float32), axis=0)}
     y = jnp.einsum("...i,io->...o", x, p["w"])
     if "b" in p:
         y = y + p["b"]
@@ -74,11 +77,13 @@ def grouped_dense(p: Params, x: jnp.ndarray, *, stats: Optional[dict] = None,
     (DESIGN.md §4); with ``per_expert_stats`` a per-expert (E, d_in) mean.
     """
     if stats is not None:
-        xf = x.astype(jnp.float32)
-        if per_expert_stats:
-            stats[name] = {"a": jnp.mean(xf, axis=1)}
-        else:
-            stats[name] = {"a": jnp.mean(xf.reshape(-1, x.shape[-1]), axis=0)}
+        with jax.named_scope(scopes.MKOR_STATS):
+            xf = x.astype(jnp.float32)
+            if per_expert_stats:
+                stats[name] = {"a": jnp.mean(xf, axis=1)}
+            else:
+                stats[name] = {"a": jnp.mean(xf.reshape(-1, x.shape[-1]),
+                                             axis=0)}
     y = jnp.einsum("eci,eio->eco", x, p["w"])
     if "b" in p:
         y = y + p["b"][:, None, :]

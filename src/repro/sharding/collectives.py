@@ -44,6 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import scopes
+
 DistSpec = Tuple[Tuple[str, int], ...]
 
 # The wire contract the dtype-discipline lint (repro.analysis) checks
@@ -342,6 +344,7 @@ def owner_sharded_map_quant(fn, arrays, dist: DistSpec, n_slots: int,
             gather_shards(scales, dist, n_slots, live))
 
 
+@scopes.scoped(scopes.OWNER_GATHER)
 def gather_shards(x: jnp.ndarray, dist: DistSpec, n_slots: int,
                   live: Optional[LiveMask] = None) -> jnp.ndarray:
     """Recombine the per-worker owned chunks into the full bank dim.

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import scopes
 from repro.core import firstorder
 from repro.core.firstorder import GradientTransformation
 from repro.models import model as model_lib
@@ -90,10 +91,21 @@ def train_batch_shapes(cfg: ModelConfig, global_batch: int, seq_len: int,
     return shapes
 
 
+def step_metrics(params, updates, grads, **metrics):
+    """The step's last stage: the updates added to the parameters, and
+    the step's metrics with the gradient and update norms."""
+    with jax.named_scope(scopes.APPLY):
+        params = firstorder.apply_updates(params, updates)
+        return params, {**metrics,
+                        "grad_norm": firstorder.global_norm(grads),
+                        "update_norm": firstorder.global_norm(updates)}
+
+
 def make_train_step(cfg: ModelConfig, optimizer: GradientTransformation,
                     *, collect_stats: bool = True,
                     donate: bool = True) -> Callable:
-    loss_fn = make_loss_fn(cfg, collect_stats=collect_stats)
+    loss_fn = scopes.scoped(scopes.FORWARD)(
+        make_loss_fn(cfg, collect_stats=collect_stats))
 
     def train_step(params, opt_state, batch):
         # Two-phase async protocol (DESIGN.md §13): the precompute tick
@@ -107,14 +119,9 @@ def make_train_step(cfg: ModelConfig, optimizer: GradientTransformation,
         updates, opt_state = optimizer.update(
             grads, opt_state, params=params, stats=aux["stats"], loss=loss,
             precomputed=optimizer.precompute is not None)
-        params = firstorder.apply_updates(params, updates)
-        metrics = {
-            "loss": loss,
-            "loss_lm": aux["loss_lm"],
-            "moe_aux": aux["moe_aux"],
-            "grad_norm": firstorder.global_norm(grads),
-            "update_norm": firstorder.global_norm(updates),
-        }
+        params, metrics = step_metrics(
+            params, updates, grads, loss=loss, loss_lm=aux["loss_lm"],
+            moe_aux=aux["moe_aux"])
         return params, opt_state, metrics
 
     return train_step
@@ -171,26 +178,26 @@ def make_dist_step_fn(grads_fn: Callable, optimizer: GradientTransformation,
         out = grads_fn(params, batch)
         loss, grads, stats = out[:3]
         extra = out[3] if len(out) > 3 else {}
-        loss = collectives.pmean(loss, dist)
         # Gradient mean as its two explicit ring-all-reduce phases with
         # the independent O(d) stat pmean interleaved between them — the
         # widest scheduling window for hiding the inversion launch inside
         # the gradient exchange (numerically identical to the fused
         # all_reduce_mean_tree; the stat pmean commutes with both halves).
-        shard, spec = collectives.flat_reduce_scatter_mean(grads, dist)
-        stats = collectives.pmean_rank1_stats(
-            stats, dist, payload_dtype=stats_payload_dtype)
-        grads = collectives.flat_all_gather_tree(shard, spec, dist)
+        with jax.named_scope(scopes.GRAD_ALLREDUCE):
+            loss = collectives.pmean(loss, dist)
+            shard, spec = collectives.flat_reduce_scatter_mean(grads, dist)
+        with jax.named_scope(scopes.STAT_ALLREDUCE):
+            stats = collectives.pmean_rank1_stats(
+                stats, dist, payload_dtype=stats_payload_dtype)
+        with jax.named_scope(scopes.GRAD_ALLREDUCE):
+            grads = collectives.flat_all_gather_tree(shard, spec, dist)
         updates, opt_state = optimizer.update(
             grads, opt_state, params=params, stats=stats, loss=loss,
             precomputed=optimizer.precompute is not None)
-        params = firstorder.apply_updates(params, updates)
-        metrics = {
-            "loss": loss,
-            **{k: collectives.pmean(v, dist) for k, v in extra.items()},
-            "grad_norm": firstorder.global_norm(grads),
-            "update_norm": firstorder.global_norm(updates),
-        }
+        with jax.named_scope(scopes.APPLY):
+            extra = {k: collectives.pmean(v, dist) for k, v in extra.items()}
+        params, metrics = step_metrics(params, updates, grads, loss=loss,
+                                       **extra)
         return params, opt_state, metrics
 
     def step(params, opt_state, batch):
@@ -220,7 +227,8 @@ def make_dist_train_step(cfg: ModelConfig,
     ``--dist``): same signature and metrics, explicit collectives.  Build
     the MKOR optimizer with ``MKORConfig.dist = collectives.dist_axes(...)``
     to owner-shard the factor inversions across the same axes."""
-    loss_fn = make_loss_fn(cfg, collect_stats=collect_stats)
+    loss_fn = scopes.scoped(scopes.FORWARD)(
+        make_loss_fn(cfg, collect_stats=collect_stats))
 
     def local_grads(params, batch):
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -278,6 +286,27 @@ def make_chunk_runner(step_fn: Callable, *, donate: bool = True) -> Callable:
     return jax.jit(run_chunk, donate_argnums=(0, 1) if donate else ())
 
 
+def run_chunk(runner: Callable, params, opt_state, batches: Sequence[Dict],
+              step: int):
+    """One chunk of the training loop: stack ``batches`` on the host, run
+    them through ``runner`` (:func:`make_chunk_runner`), fetch the chunk's
+    metrics.  Returns ``(params, opt_state, metrics)``, the metrics on the
+    host and stacked per step.
+
+    Each part is a host span of the profiler's trace (``stack_batches``,
+    ``dispatch``, ``device_get``), inside a ``train`` step span numbered by
+    the chunk's first step: on the trace's one clock with the device ops
+    the runner's named scopes label (repro/scopes.py)."""
+    with jax.profiler.StepTraceAnnotation(scopes.STEP_SPAN, step_num=step):
+        with jax.profiler.TraceAnnotation(scopes.STACK_BATCHES):
+            stacked = stack_batches(batches)
+        with jax.profiler.TraceAnnotation(scopes.DISPATCH):
+            params, opt_state, metrics = runner(params, opt_state, stacked)
+        with jax.profiler.TraceAnnotation(scopes.DEVICE_GET):
+            metrics = jax.device_get(metrics)       # one sync per chunk
+    return params, opt_state, metrics
+
+
 def train_epoch(step_fn: Callable, params, opt_state, batches, *,
                 chunk: int = 8, donate: bool = True,
                 runner: Optional[Callable] = None,
@@ -302,9 +331,8 @@ def train_epoch(step_fn: Callable, params, opt_state, batches, *,
 
     def flush(buf):
         nonlocal params, opt_state
-        params, opt_state, metrics = runner(params, opt_state,
-                                            stack_batches(buf))
-        metrics = jax.device_get(metrics)          # one sync per chunk
+        params, opt_state, metrics = run_chunk(runner, params, opt_state,
+                                               buf, len(history))
         for k in range(len(buf)):
             m = {key: float(v[k]) for key, v in metrics.items()}
             if hooks is not None:

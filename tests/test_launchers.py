@@ -98,6 +98,45 @@ def test_train_main_returns_history(monkeypatch, tmp_path, extra):
     assert compiled.count("jit(run_chunk)") == 1, compiled
 
 
+def test_train_main_profiles_chunks(monkeypatch, tmp_path):
+    """--profile-dir traces the chunks after the first two: the written
+    .xplane.pb holds the chunk loop's host spans.  The loop is
+    training/loop.run_chunk, the one train_epoch runs: over the same
+    batches both log the same losses."""
+    import glob
+
+    import jax
+    from repro import scopes
+    from repro.configs import registry
+    from repro.data import pipeline
+    from repro.launch import compile_cache, train
+    from repro.models import model as model_lib
+    from repro.training import loop
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / "cache"))
+    argv = TINY[:TINY.index("--steps")] + ["--steps", "6"] \
+        + TINY[TINY.index("--steps") + 2:]
+    prof = tmp_path / "profile"
+    history = train.main(argv + ["--profile-dir", str(prof),
+                                 "--profile-chunks", "1"])
+    files = glob.glob(str(prof / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    assert len(files) == 1, files
+    data = jax.profiler.ProfileData.from_file(files[0])
+    spans = [e.name for plane in data.planes for line in plane.lines
+             for e in line.events if e.name in scopes.HOST_SPANS]
+    assert sorted(spans) == sorted(scopes.HOST_SPANS), spans
+
+    cfg = registry.get_config("bert-large").reduced()
+    opt, _ = train.build_optimizer(
+        "mkor", train.build_schedule("cosine", 1e-3, 6), inv_freq=2)
+    params = model_lib.init_params(jax.random.PRNGKey(0), cfg)
+    ds = pipeline.make_dataset(cfg, global_batch=4, seq_len=16, seed=0)
+    _, _, epoch = loop.train_epoch(
+        loop.make_train_step(cfg, opt), params, opt.init(params),
+        [pipeline.make_batch(ds, i) for i in range(6)], chunk=2)
+    assert [h["loss"] for h in history] == [h["loss"] for h in epoch]
+
+
 def test_use_pallas_interprets_only_on_a_requested_cpu(monkeypatch):
     import jax
     from repro.launch import train

@@ -13,6 +13,7 @@ and under pytest-xdist every worker imports every test file.  Keep these
 tests in this one file, so that a single worker loads the library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from repro import scopes
 from repro.kernels import ops
 
 D_MODEL, D_FF = 1024, 4096           # bert-large (configs/bert_large.py)
@@ -47,13 +49,18 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, *shapes, kernel):
     """Compiled text of ``fn`` for the described chip; asserts that a
-    Pallas kernel (a ``tpu_custom_call``) is in it."""
+    Pallas kernel (a ``tpu_custom_call``) is in it, and that every such
+    call is an instruction named after ``kernel``, the name the kernel
+    gives its ``pallas_call`` (so the device trace names it too)."""
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert calls
+    assert all(re.match(rf"(vmap_)?{kernel}[_.]", c) for c in calls), calls
     return text
 
 
@@ -69,7 +76,7 @@ def test_fused_smw_compiles(one_chip, quant):
         def fn(j, v):
             return ops.smw_rank1_update(j, v, gamma=0.9)
         shapes = [((d, d), jnp.bfloat16), ((d,), jnp.float32)]
-    _compile(fn, one_chip, *shapes)
+    _compile(fn, one_chip, *shapes, kernel=scopes.SMW_KERNEL)
 
 
 @pytest.mark.parametrize("with_pivot", [False, True],
@@ -83,7 +90,7 @@ def test_fused_block_smw_compiles(one_chip, rank, with_pivot):
         return ops.smw_block_update(j, v, gamma=0.9, n_valid=rank,
                                     with_pivot=with_pivot)
     _compile(fn, one_chip, ((D_FF, D_FF), jnp.bfloat16),
-             ((rank, D_FF), jnp.float32))
+             ((rank, D_FF), jnp.float32), kernel=scopes.BLOCK_SMW_KERNEL)
 
 
 def test_fused_precondition_compiles(one_chip):
@@ -95,7 +102,7 @@ def test_fused_precondition_compiles(one_chip):
     def fn(l_inv, r_inv, g):
         return ops.fused_precondition(l_inv, r_inv, g)
     _compile(fn, one_chip, ((d, d), jnp.bfloat16), ((d, d), jnp.bfloat16),
-             ((d, d), jnp.float32))
+             ((d, d), jnp.float32), kernel=scopes.PRECOND_KERNEL)
     assert ops.fallback_counts() == before
 
 
@@ -111,5 +118,6 @@ def test_fused_precondition_fallback_compiles(one_chip):
         return ops.fused_precondition(l_inv, r_inv, g)
     with pytest.warns(ops.PallasFallbackWarning):
         _compile(fn, one_chip, ((d_out, d_out), jnp.bfloat16),
-                 ((d_in, d_in), jnp.bfloat16), ((d_in, d_out), jnp.float32))
+                 ((d_in, d_in), jnp.bfloat16), ((d_in, d_out), jnp.float32),
+                 kernel=scopes.MATMUL_KERNEL)
     assert ops.fallback_counts().get(key, 0) == before + 1
