@@ -224,16 +224,26 @@ def check_pallas_kernels(target) -> List[Diagnostic]:
     static pre-dispatch check: for every bucket the manifest implies,
     plan the exact kernel dispatches (ops.bucket_kernel_plans — the same
     plans the runtime consumes) and diagnose over-budget dispatches,
-    tile misalignment, and Gauss-Jordan rank bounds per bucket."""
+    tile misalignment, and Gauss-Jordan rank bounds per bucket.  Where
+    the fused precondition falls back, the two matmul plans it falls back
+    to (ops.precondition_matmul_plans, at the bucket's gradient dtype,
+    ``meta["grad_dtypes"]``, else fp32) are reported and checked too."""
     out: List[Diagnostic] = []
     manifest = target.meta.get("manifest")
     cfg = target.meta.get("mkor_cfg")
     if manifest is None or cfg is None:
         return out
+    grad_dtypes = target.meta.get("grad_dtypes", {})
+    quant = getattr(cfg, "factor_quant", "none")
     for b in manifest:
         plans = kernel_ops.bucket_kernel_plans(
             b.d_in, b.d_out, rank=cfg.rank, factor_dtype=cfg.factor_dtype,
-            factor_quant=getattr(cfg, "factor_quant", "none"))
+            factor_quant=quant)
+        if not plans[-1].fits:
+            plans += kernel_ops.precondition_matmul_plans(
+                b.d_in, b.d_out, factor_dtype=cfg.factor_dtype,
+                factor_quant=quant,
+                grad_dtype=grad_dtypes.get(b.bucket_id, "float32"))
         for p in plans:
             ctx = dict(bucket=b.bucket_id, kernel=p.kernel,
                        dims=list(p.dims), block=list(p.block),
@@ -269,6 +279,16 @@ def check_pallas_kernels(target) -> List[Diagnostic]:
                     f"bucket {b.bucket_id}: {p.kernel} block {p.block} "
                     f"below the 128 lane width on a >128 dim — wasted "
                     f"MXU lanes", target, **ctx))
+            if p.kernel == "matmul":
+                out.append(_d(
+                    "pallas-kernels", "pallas.precond-matmul-plan",
+                    Severity.INFO,
+                    f"bucket {b.bucket_id}: fallback matmul "
+                    f"{'x'.join(map(str, p.dims))} on blocks "
+                    f"{'x'.join(map(str, p.block))}, grid "
+                    f"{'x'.join(map(str, p.grid))}, "
+                    f"{p.vmem_bytes / 2**20:.1f}MB VMEM (budget "
+                    f"{p.vmem_budget / 2**20:.0f}MB)", target, **ctx))
             if p.kernel == "fused_block_smw":
                 if p.rank > 128:
                     out.append(_d(

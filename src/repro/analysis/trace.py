@@ -92,6 +92,8 @@ def _target_meta(cfg, params, mkor_cfg: MKORConfig,
         "grad_f32_bytes": grad_bytes,
         "stats_f32_bytes": stats_bytes,
         "bucket_comm": comm,
+        "grad_dtypes": {b.bucket_id: str(statlib.tree_get(
+            params, b.paths[0])["w"].dtype) for b in manifest},
     }
 
 

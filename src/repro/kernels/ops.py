@@ -181,6 +181,100 @@ def fused_block_smw_plan(d: int, rank: int, *, block: int = 0,
         fits=vmem <= _FUSED_PRECOND_VMEM_BUDGET, falls_back=False)
 
 
+# The tiled matmul's largest (block_m, block_k, block_n) and its VMEM
+# budget (a v5e core has 128 MiB of VMEM; Mosaic's default scoped limit is
+# 16 MiB, so the call raises it to what the plan needs).  On a v5e at the
+# rwkv6-3b slices 1280-wide blocks ran the 411 GFLOP bf16 products at
+# 179-181 TFLOP/s, 640-wide ones at 164; the split products varied by 2%
+# across the tilings tried (PERF.md).
+MATMUL_BLOCK_CAP = (1280, 1280, 1280)
+MATMUL_VMEM_BUDGET = 64 * 2**20
+
+
+def _lane_blocks(d: int, cap: int) -> Tuple[int, ...]:
+    """Blocks for a matmul dim, largest first: multiples of 128 up to
+    ``cap`` that divide d padded to 128, or one block for a dim ≤ 128."""
+    if d <= 128:
+        return (_pick_block(d),)
+    dp = _padded_size(d, 128)
+    return tuple(b for b in range(min(cap, dp) // 128 * 128, 0, -128)
+                 if dp % b == 0)
+
+
+def _matmul_vmem(bm: int, bk: int, bn: int, ia: int, ib: int,
+                 terms: int) -> int:
+    """VMEM of one tiled-matmul grid step: double-buffered A, B and fp32
+    out tiles (the out tile is the accumulator), the fp32 tile product,
+    and the split's working copies of an fp32 operand tile (or the fp32
+    upcasts of Mosaic's fp32 dot)."""
+    vmem = 2 * (bm * bk * ia + bk * bn * ib + bm * bn * 4) + bm * bn * 4
+    if terms == 0:
+        vmem += bm * bk * 4 * (ia != 4) + bk * bn * 4 * (ib != 4)
+    elif terms > 1:
+        split = bm * bk if ia == 4 else bk * bn
+        vmem += split * (4 + 2 * terms) + bm * bn * 4
+    return vmem
+
+
+def matmul_plan(m: int, k: int, n: int, *, a_dtype="bfloat16",
+                b_dtype="bfloat16", block=0) -> KernelPlan:
+    """What :func:`pallas_matmul` will do for (m, k) @ (k, n): per dim
+    the largest lane-aligned block under ``MATMUL_BLOCK_CAP`` that divides
+    the dim padded to 128; the largest block (K first on a tie) steps
+    down until the VMEM plan fits ``MATMUL_VMEM_BUDGET``.  ``block`` (an
+    int, or (bm, bk, bn)) forces the blocks."""
+    ia, ib = jnp.dtype(a_dtype).itemsize, jnp.dtype(b_dtype).itemsize
+    terms = mm.mxu_terms(a_dtype, b_dtype)
+    if block:
+        bm, bk, bn = (block,) * 3 if isinstance(block, int) else block
+    else:
+        cands = [list(_lane_blocks(d, c))
+                 for d, c in zip((m, k, n), MATMUL_BLOCK_CAP)]
+        while (_matmul_vmem(*(c[0] for c in cands), ia, ib, terms)
+               > MATMUL_VMEM_BUDGET):
+            shrink = [i for i in (1, 0, 2) if len(cands[i]) > 1]
+            if not shrink:
+                break
+            cands[max(shrink, key=lambda i: cands[i][0])].pop(0)
+        bm, bk, bn = (c[0] for c in cands)
+    mp, kp, np_ = (_padded_size(d, b) for d, b in ((m, bm), (k, bk),
+                                                     (n, bn)))
+    vmem = _matmul_vmem(bm, bk, bn, ia, ib, terms)
+    return KernelPlan(
+        kernel="matmul", dims=(m, k, n), padded=(mp, kp, np_),
+        block=(bm, bk, bn), grid=(mp // bm, np_ // bn, kp // bk), rank=1,
+        vmem_bytes=int(vmem), vmem_budget=MATMUL_VMEM_BUDGET,
+        fits=vmem <= MATMUL_VMEM_BUDGET, falls_back=False)
+
+
+def _vmem_limit(plan: KernelPlan) -> int:
+    """The call's scoped VMEM limit: the plan's bytes and half again for
+    what Mosaic adds, never under its 16 MiB default nor over 112 MiB."""
+    return max(16 * 2**20, min(plan.vmem_bytes * 3 // 2, 112 * 2**20))
+
+
+def precondition_matmul_plans(d_in: int, d_out: int, *,
+                              factor_dtype="bfloat16",
+                              factor_quant: str = "none",
+                              grad_dtype="bfloat16",
+                              block: int = 0) -> Tuple[KernelPlan, ...]:
+    """The two :func:`matmul_plan` s of :func:`two_sided_precondition`
+    for one (d_in, d_out) slice, in dispatch order: what
+    :func:`fused_precondition` runs when its own plan does not fit.  The
+    intermediate is fp32, and so are int8 factors, dequantized first."""
+    f = statlib.factor_storage_dtype(factor_dtype, factor_quant)
+    f, g, t = "float32" if f == "int8" else f, grad_dtype, "float32"
+    if _right_first(d_in, d_out):
+        return (matmul_plan(d_in, d_out, d_out, a_dtype=g, b_dtype=f,
+                            block=block),
+                matmul_plan(d_in, d_in, d_out, a_dtype=f, b_dtype=t,
+                            block=block))
+    return (matmul_plan(d_in, d_in, d_out, a_dtype=f, b_dtype=g,
+                        block=block),
+            matmul_plan(d_in, d_out, d_out, a_dtype=t, b_dtype=f,
+                        block=block))
+
+
 def bucket_kernel_plans(d_in: int, d_out: int, *, rank: int = 1,
                         factor_dtype="bfloat16", factor_quant: str = "none",
                         block: int = 0) -> Tuple[KernelPlan, ...]:
@@ -361,29 +455,43 @@ def smw_block_update_banked(j: jnp.ndarray, v: jnp.ndarray, n_valid, *,
     return out.reshape(out_shape)
 
 
-def pallas_matmul(a: jnp.ndarray, b: jnp.ndarray, *, block: int = 0,
-                  out_dtype=jnp.float32, interpret: bool = False):
+def pallas_matmul(a: jnp.ndarray, b: jnp.ndarray, *, block=0,
+                  interpret: bool = False):
+    """(M, K) @ (K, N) → fp32 through the tiled kernel, on the blocks and
+    VMEM limit of :func:`matmul_plan`; ``block`` forces the blocks."""
     m, k = a.shape
     _, n = b.shape
-    blk = block or min(_pick_block(m), _pick_block(n), _pick_block(k))
-    ap = _pad_to(a, blk, (0, 1))
-    bp = _pad_to(b, blk, (0, 1))
-    out = mm.matmul(ap, bp, block_m=blk, block_n=blk, block_k=blk,
-                    out_dtype=out_dtype, interpret=interpret)
+    plan = matmul_plan(m, k, n, a_dtype=a.dtype, b_dtype=b.dtype,
+                       block=block)
+    bm, bk, bn = plan.block
+    ap = _pad_to(_pad_to(a, bm, (0,)), bk, (1,))
+    bp = _pad_to(_pad_to(b, bk, (0,)), bn, (1,))
+    out = mm.matmul(ap, bp, block_m=bm, block_n=bn, block_k=bk,
+                    vmem_limit_bytes=_vmem_limit(plan), interpret=interpret)
     return out[:m, :n]
+
+
+def _right_first(d_in: int, d_out: int) -> bool:
+    """R (G L) when d_in < d_out, else (R G) L: the product of G with its
+    larger factor comes first, so it multiplies the stored (bf16) G and
+    the fp32 intermediate rides the smaller product."""
+    return d_in < d_out
 
 
 def two_sided_precondition(l_inv: jnp.ndarray, r_inv: jnp.ndarray,
                            g_w: jnp.ndarray, *, block: int = 0,
                            interpret: bool = False) -> jnp.ndarray:
-    """ΔW = R⁻¹ G L⁻¹ via two tiled Pallas matmuls.  Extra leading dims of
-    ``g_w`` (experts under shared factors) are vmapped."""
+    """ΔW = R⁻¹ G L⁻¹ via two tiled Pallas matmuls, in the order of
+    :func:`_right_first`.  Extra leading dims of ``g_w`` (experts under
+    shared factors) are vmapped."""
     if g_w.ndim > 2:
         fn = partial(two_sided_precondition, l_inv, r_inv, block=block,
                      interpret=interpret)
         return jax.vmap(fn)(g_w)
-    t = pallas_matmul(r_inv, g_w, block=block, interpret=interpret)
-    return pallas_matmul(t, l_inv, block=block, interpret=interpret)
+    mm_ = partial(pallas_matmul, block=block, interpret=interpret)
+    if _right_first(*g_w.shape):
+        return mm_(r_inv, mm_(g_w, l_inv))
+    return mm_(mm_(r_inv, g_w), l_inv)
 
 
 def _fused_precond_fits(d_in: int, d_out: int, r_inv, l_inv,

@@ -601,6 +601,38 @@ def test_kernel_plans_match_known_shapes():
         "fused_block_smw", "fused_block_smw", "fused_precond"]
 
 
+@pytest.mark.parametrize("grad_dtypes,passes", [({}, "float32"),
+                                                 (None, "bfloat16")],
+                         ids=["no_dtypes", "bf16_grads"])
+def test_pallas_lint_reports_fallback_matmul_plans(grad_dtypes, passes):
+    """Where the fused precondition does not fit, the lint reports the
+    two matmul plans it falls back to, from ops.precondition_matmul_plans
+    at the bucket's gradient dtype (fp32 when the target names none)."""
+    params = {"layer": {
+        "w": jax.ShapeDtypeStruct((2560, 8960), jnp.bfloat16),
+        "probe": jax.ShapeDtypeStruct((8960,), jnp.float32)}}
+    cfg = MKORConfig(exclude=())
+    manifest = manifest_for(params, cfg)
+    if grad_dtypes is None:
+        grad_dtypes = {b.bucket_id: "bfloat16" for b in manifest}
+    target = LintTarget(
+        name="fixture/rwkv6-channel-mix", kind="custom",
+        meta={"manifest": manifest, "mkor_cfg": cfg,
+              "grad_dtypes": grad_dtypes})
+    report = run_checkers([target], names=["pallas-kernels"])
+    assert report.exit_code() == 0, report.render()
+    assert report.by_code("pallas.fused-precond-fallback")
+    infos = report.by_code("pallas.precond-matmul-plan")
+    want = ops.precondition_matmul_plans(2560, 8960, grad_dtype=passes)
+    assert [d.context["dims"] for d in infos] == [list(p.dims)
+                                                  for p in want]
+    assert [d.context["block"] for d in infos] == [list(p.block)
+                                                   for p in want]
+    assert [d.context["vmem_bytes"] for d in infos] == [p.vmem_bytes
+                                                        for p in want]
+    assert all(d.severity == Severity.INFO for d in infos)
+
+
 def test_fused_precond_fallback_counter_vmem():
     ops.reset_fallback_counts()
     big = jax.ShapeDtypeStruct((4096, 4096), jnp.float32)

@@ -98,6 +98,110 @@ def test_two_sided_precondition_expert_broadcast():
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
+# ---------------------------------------------------------------------- #
+# The tiled matmul at its operands' precision (kernels/matmul.py)
+# ---------------------------------------------------------------------- #
+def _rel_fro(got, want) -> float:
+    got = np.asarray(got, np.float64)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def _operands(m, k, n, a_dtype, b_dtype):
+    a = jax.random.normal(jax.random.key(3), (m, k), jnp.float32)
+    b = jax.random.normal(jax.random.key(4), (k, n), jnp.float32)
+    return a.astype(a_dtype), b.astype(b_dtype)
+
+
+MATMUL_CASES = [((256, 384, 128), 0), ((300, 200, 100), 0),
+                ((256, 384, 256), (128, 128, 128))]
+
+
+@pytest.mark.parametrize("shape,block", MATMUL_CASES)
+def test_matmul_bf16_operands_one_exact_pass(shape, block):
+    """bf16 x bf16 goes to the MXU as bf16 (one pass): the products are
+    exact, so it matches the fp32 product of the upcast values to fp32
+    accumulation."""
+    a, b = _operands(*shape, jnp.bfloat16, jnp.bfloat16)
+    assert mm.mxu_terms(a.dtype, b.dtype) == 1
+    got = ops.pallas_matmul(a, b, block=block, interpret=True)
+    want = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
+    assert _rel_fro(got, want) < 1e-6
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("shape,block", MATMUL_CASES)
+@pytest.mark.parametrize("f32_side", ["left", "right"])
+def test_matmul_split_f32_operand_keeps_f32_precision(shape, block,
+                                                      f32_side):
+    """fp32 against bf16: the fp32 tile is split into bf16 terms in the
+    kernel.  Against float64 it stays at fp32-level error, about a
+    thousand times under one bf16 rounding of the fp32 operand (what
+    Mosaic's fp32 dot does on the chip)."""
+    dts = ((jnp.float32, jnp.bfloat16) if f32_side == "left"
+           else (jnp.bfloat16, jnp.float32))
+    a, b = _operands(*shape, *dts)
+    assert mm.mxu_terms(a.dtype, b.dtype) == mm.SPLIT_TERMS >= 2
+    got = ops.pallas_matmul(a, b, block=block, interpret=True)
+    want = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
+    assert _rel_fro(got, want) < 1e-5
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * np.abs(want).max())
+    one_pass = (np.asarray(a.astype(jnp.bfloat16), np.float64)
+                @ np.asarray(b.astype(jnp.bfloat16), np.float64))
+    assert _rel_fro(got, want) < _rel_fro(one_pass, want) / 100
+
+
+@pytest.mark.parametrize("shape,block", MATMUL_CASES)
+def test_matmul_f32_operands_keep_f32_dot(shape, block):
+    """fp32 x fp32 (fp32 factor configurations, int8 banks dequantized on
+    the fallback path) keeps the fp32 dot, unchanged."""
+    a, b = _operands(*shape, jnp.float32, jnp.float32)
+    assert mm.mxu_terms(a.dtype, b.dtype) == 0
+    got = ops.pallas_matmul(a, b, block=block, interpret=True)
+    np.testing.assert_allclose(got, ref.matmul_ref(a, b), rtol=1e-5,
+                               atol=1e-4)
+
+
+@pytest.mark.parametrize("din,dout", [(128, 384), (384, 128), (256, 256)],
+                         ids=["din_lt_dout", "din_gt_dout", "din_eq_dout"])
+def test_two_sided_precondition_bf16_both_orders(din, dout):
+    """bf16 factors and gradient, as the cells store them: R (G L) when
+    d_in < d_out, else (R G) L, both against the fp32 reference."""
+    g = jax.random.normal(jax.random.key(0), (din, dout),
+                          jnp.float32).astype(jnp.bfloat16)
+    l = _pd_matrix(jax.random.key(1), dout, jnp.bfloat16)
+    r = _pd_matrix(jax.random.key(2), din, jnp.bfloat16)
+    first, second = ops.precondition_matmul_plans(din, dout)
+    if din < dout:
+        assert (first.dims, second.dims) == ((din, dout, dout),
+                                             (din, din, dout))
+    else:
+        assert (first.dims, second.dims) == ((din, din, dout),
+                                             (din, dout, dout))
+    got = ops.two_sided_precondition(l, r, g, interpret=True)
+    want = ref.two_sided_precondition_ref(l, r, g)
+    assert _rel_fro(got, np.asarray(want, np.float64)) < 1e-5
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("din,dout", [(2560, 8960), (8960, 2560),
+                                      (2560, 2560)])
+def test_precondition_matmul_plans_at_rwkv6_slices(din, dout):
+    """At the rwkv6-3b slices each fallback matmul plan takes lane-aligned
+    blocks that divide the dims (no padding) and fits its VMEM budget and
+    the call's limit; checked on the plans alone."""
+    for p in ops.precondition_matmul_plans(din, dout):
+        assert p.kernel == "matmul" and p.lane_aligned and p.fits
+        assert p.padded == p.dims
+        assert all(d % blk == 0 for d, blk in zip(p.dims, p.block))
+        assert p.block == (1280, 1280, 1280)
+        assert p.vmem_bytes <= ops._vmem_limit(p) < 128 * 2**20
+        m, k, n = p.dims
+        assert p.grid == (m // 1280, n // 1280, k // 1280)
+
+
 @pytest.mark.parametrize("variant", ["paper", "exact_smw"])
 def test_pallas_path_matches_jnp_path_in_mkor(variant):
     """MKOR with use_pallas=True produces the same update as the jnp path

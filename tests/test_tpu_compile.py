@@ -121,3 +121,23 @@ def test_fused_precondition_fallback_compiles(one_chip):
                  ((d_in, d_in), jnp.bfloat16), ((d_in, d_out), jnp.float32),
                  kernel=scopes.MATMUL_KERNEL)
     assert ops.fallback_counts().get(key, 0) == before + 1
+
+
+@pytest.mark.parametrize("d_in,d_out", [(2560, 8960), (8960, 2560)])
+def test_fused_precondition_fallback_compiles_at_rwkv6_slices(
+        one_chip, d_in, d_out):
+    """rwkv6-3b's wide slices, bf16 factors and a bf16 gradient, as the
+    cell runs them: the fallback's two matmuls compile on the 1280-wide
+    tiles of their plans, with the VMEM limit the plans raise."""
+    plans = ops.precondition_matmul_plans(d_in, d_out)
+    assert all(p.block == (1280, 1280, 1280) for p in plans)
+
+    def fn(l_inv, r_inv, g):
+        return ops.fused_precondition(l_inv, r_inv, g)
+    with pytest.warns(ops.PallasFallbackWarning):
+        text = _compile(fn, one_chip, ((d_out, d_out), jnp.bfloat16),
+                        ((d_in, d_in), jnp.bfloat16),
+                        ((d_in, d_out), jnp.bfloat16),
+                        kernel=scopes.MATMUL_KERNEL)
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          text)) == 2
