@@ -64,17 +64,21 @@ def cell_spec(name: str) -> Dict:
 
 
 def sizes(model: Dict) -> Dict:
-    """The configuration file's published keys as the reference and the
-    counts read them; ``structure`` names the program's model fields the
-    file expects."""
+    """The configuration file's keys as the reference and the counts read
+    them: every top-level key as the file has it, and under the names
+    below the sizes derived from it; ``structure`` names the program's
+    model fields the file expects."""
     st = model["structure"]
     d = model["hidden_size"]
-    head_dim = model.get("head_size") or d // model["num_attention_heads"]
+    head_dim = model.get("head_dim") or model.get("head_size") \
+        or d // model["num_attention_heads"]
+    n_heads = model.get("num_attention_heads") or d // head_dim
     eps = next(model[k] for k in ("rms_norm_eps", "layer_norm_eps",
                                   "layer_norm_epsilon") if k in model)
     return {
-        "d_model": d, "n_heads": d // head_dim,
-        "n_kv_heads": model.get("num_key_value_heads", d // head_dim),
+        **model,
+        "d_model": d, "n_heads": n_heads,
+        "n_kv_heads": model.get("num_key_value_heads", n_heads),
         "head_dim": head_dim, "d_ff": model["intermediate_size"],
         "vocab": model["vocab_size"], "n_layers": model["num_hidden_layers"],
         "norm": st["norm"], "eps": eps, "gated": st["gated_mlp"],
@@ -84,22 +88,44 @@ def sizes(model: Dict) -> Dict:
     }
 
 
+def _stated(obj, want: Dict, where: str):
+    """``obj`` (a dataclass) with the fields ``want`` states; a nested
+    dataclass field takes a dict of its own fields.  A key that names no
+    field is refused."""
+    fields = {f.name for f in dataclasses.fields(obj)}
+    changes = {}
+    for k, v in want.items():
+        if k not in fields:
+            raise SystemExit(f"{where}: the configuration file states "
+                             f"{k!r}, which the program's model has not")
+        have = getattr(obj, k)
+        if isinstance(v, dict):
+            if not dataclasses.is_dataclass(have):
+                raise SystemExit(f"{where}.{k}: the program's model has "
+                                 f"{have!r} there, not fields to state")
+            v = _stated(have, v, f"{where}.{k}")
+        changes[k] = v
+    return dataclasses.replace(obj, **changes)
+
+
 def model_config(model: Dict, sz: Dict):
-    """The registry's ModelConfig with the file's numbers; refuses a file
-    whose ``structure`` the registry entry does not have."""
+    """The registry's ModelConfig with the file's numbers and with the
+    fields its ``structure`` states (nested ones, such as a chip's share
+    of the experts, as dicts).  Refuses a field the program's model does
+    not have, and a depth that is not whole periods of the pattern."""
     from repro.configs import registry
     base = registry.get_config(model["arch"])
+    period = len(base.pattern)
+    if sz["n_layers"] % period:
+        raise SystemExit(f"{model['arch']}: {sz['n_layers']} layers are not "
+                         f"whole periods of its {period}-layer pattern")
     cfg = dataclasses.replace(
         base, n_layers=sz["n_layers"], d_model=sz["d_model"],
-        n_heads=sz["n_heads"], n_kv_heads=sz["n_kv_heads"], head_dim=0,
-        d_ff=sz["d_ff"], vocab_size=sz["vocab"], norm_eps=sz["eps"],
-        rope_theta=sz["rope_theta"], dtype=model["torch_dtype"])
-    want = model["structure"]
-    have = {k: getattr(cfg, k) for k in want}
-    if have != want or len(cfg.pattern) != 1:
-        raise SystemExit(f"{model['arch']}: the program's model is {have}, "
-                         f"the configuration file states {want}")
-    return cfg
+        n_heads=sz["n_heads"], n_kv_heads=sz["n_kv_heads"],
+        head_dim=sz["head_dim"], d_ff=sz["d_ff"], vocab_size=sz["vocab"],
+        norm_eps=sz["eps"], rope_theta=sz["rope_theta"],
+        dtype=model["torch_dtype"])
+    return _stated(cfg, model["structure"], model["arch"])
 
 
 class Run:
@@ -484,15 +510,16 @@ def kernel_work(run: Run, mcfg, params, count0: int, n_steps: int):
 def traced(run: Run, drv: Driver, opt, mcfg, tokens_per_chunk, counter,
            log):
     """The --trace 1 run: a traced window, then host-clock chunk times of
-    MKOR and of a LAMB-only twin.  Returns (metrics, extra, peak,
-    compilations in the traced and timed MKOR chunks)."""
+    MKOR and of a LAMB-only twin, then each stage's device time by the
+    compiled chunk's text.  Returns (metrics, extra, peak, compilations
+    in the traced and timed MKOR chunks)."""
     import counts
     import tracefile
     from repro.training import loop
     jax = run.jax
     stacked = loop.stack_batches(drv.pool[:run.chunk])
-    ma = drv.runner.lower(drv.params, drv.opt_state, stacked) \
-        .compile().memory_analysis()
+    compiled = drv.runner.lower(drv.params, drv.opt_state, stacked).compile()
+    ma = compiled.memory_analysis()
     if ma is not None:
         log(f"compiled chunk: arguments {ma.argument_size_in_bytes / GIB:.3f}"
             f" GiB, temporaries {ma.temp_size_in_bytes / GIB:.3f} GiB, "
@@ -505,8 +532,7 @@ def traced(run: Run, drv: Driver, opt, mcfg, tokens_per_chunk, counter,
     compiles0 = counter.n
     jax.profiler.start_trace(str(TRACE_DIR))
     t0 = time.perf_counter()
-    for _ in range(n_trace):
-        drv.chunk()
+    step_metrics = [drv.chunk() for _ in range(n_trace)]
     window_host_s = time.perf_counter() - t0
     jax.profiler.stop_trace()
     events = tracefile.load(TRACE_DIR)
@@ -531,6 +557,7 @@ def traced(run: Run, drv: Driver, opt, mcfg, tokens_per_chunk, counter,
     lamb_chunk_s = twin.timed(n_twin)
     twin.params = twin.opt_state = None
     drv.steps, drv.failed = steps, failed
+    names = tracefile.op_names(compiled.as_text())
 
     ctx = {
         "events": events, "chips": run.chips,
@@ -541,6 +568,12 @@ def traced(run: Run, drv: Driver, opt, mcfg, tokens_per_chunk, counter,
         "work": work, "log": log,
         "mkor_chunk_s": mkor_chunk_s, "lamb_chunk_s": lamb_chunk_s,
         "mkor_state_bytes": mkor_state, "lamb_state_bytes": lamb_state,
+        "stages": {"seconds": tracefile.stage_seconds(events, names),
+                   "busy_s": tracefile.length(tracefile.busy(events, "0"))
+                   * 1e-9},
+        "step_metrics": {k: np.concatenate([np.ravel(m[k])
+                                            for m in step_metrics])
+                         for k in step_metrics[0]},
     }
     metrics = {}
     for m in run.cell["per_layer"]:

@@ -9,18 +9,22 @@ update is rounded onto them, as training in that dtype does; optimizer
 moments and Kronecker factors stay float32.
 
 For each dense layer ({"w", "probe"}) outside ``exclude``, with input mean
-a = E[x] and probe gradient g = E[dL/dy]:
+a = E[x] and probe gradient g = E[dL/dy], and for each slice of its
+leading dimensions (a weight (depth, experts, d_in, d_out) has depth x
+experts slices, each with its own factor pair):
     on its phase step (count % inv_freq == phase of its shape bucket):
         F <- stabilize(F);  F <- gamma F + c (F v)(F v)^T,
         c = (1 - gamma) / (gamma^2 (1 + gamma (1 - gamma) v^T F v)),
         v = g for L (d_out x d_out), a for R (d_in x d_in)
-    dW = R G L, rescaled to the Frobenius norm of G per layer slice
+    dW = R G L, rescaled to the Frobenius norm of G of the slice
 stabilize: where max|F| > threshold, F <- zeta F + (1 - zeta) I, then
-scaled back to the threshold if still above it.  Shape buckets are the
-layers of one (d_in, d_out, depth) signature, ordered by the id string
-"{d_in}x{d_out}_s{depth}"; bucket i has phase i mod inv_freq.  Probes are
-never stepped.  LAMB then steps every leaf, its trust ratio over the
-whole (depth-stacked) leaf.
+scaled back to the threshold if still above it, each slice by its own
+max.  a and g have one vector per slice (the probe's gradient read as
+lead + (d_out,), so an expert's probe (experts, 1, d_out) is one too).
+Shape buckets are the layers of one (d_in, d_out, leading dimensions)
+signature, ordered by the id string "{d_in}x{d_out}_s{depth}[x{experts}]";
+bucket i has phase i mod inv_freq.  Probes are never stepped.  LAMB then
+steps every leaf, its trust ratio over the whole (stacked) leaf.
 """
 from __future__ import annotations
 
@@ -107,6 +111,14 @@ def _precondition(l, r, g):
     return d * (gn / jnp.maximum(jnp.sqrt(jnp.sum(d * d)), 1e-30))
 
 
+def per_slice(fn, lead, *xs):
+    """``fn`` of one slice, mapped over every slice of the leading
+    dimensions ``lead`` of each of ``xs`` (flattened into one axis)."""
+    n = math.prod(lead)
+    out = jax.vmap(fn)(*(x.reshape((n,) + x.shape[len(lead):]) for x in xs))
+    return out.reshape(lead + out.shape[1:])
+
+
 def init_state(params, opt):
     layers = mkor_layers(params, opt)
     factors = {}
@@ -153,22 +165,27 @@ def make_step(loss_and_stats, sz, opt):
             count = state["count"]
             layers = mkor_layers(params, opt)
             factors = {}
+
+            def factor_update(f, v):
+                f = _stabilize(f, opt["threshold"], opt["zeta"])
+                return _smw(f, v, opt["gamma"])
+
             for key, lay in layers.items():
                 path = lay["path"]
+                gw = _get(grads, path)["w"]
+                lead = gw.shape[:-2]
                 a = _get(stats, path)
-                g = _get(grads, path)["probe"]
+                g = _get(grads, path)["probe"].reshape(lead + (lay["d_out"],))
                 f = state["factors"][key]
                 do = count % opt["inv_freq"] == lay["phase"]
-                stab = jax.vmap(partial(_stabilize, thr=opt["threshold"],
-                                        zeta=opt["zeta"]))
-                smw = jax.vmap(partial(_smw, gamma=opt["gamma"]))
-                l = jnp.where(do, smw(stab(f["l"]), g), f["l"])
-                r = jnp.where(do, smw(stab(f["r"]), a), f["r"])
+                l = jnp.where(do, per_slice(factor_update, lead, f["l"], g),
+                              f["l"])
+                r = jnp.where(do, per_slice(factor_update, lead, f["r"], a),
+                              f["r"])
                 factors[key] = {"l": l, "r": r}
-                gw = _get(grads, path)["w"]
                 grads = _set(grads, path, {**_get(grads, path),
-                                           "w": jax.vmap(_precondition)(
-                                               l, r, gw)})
+                                           "w": per_slice(_precondition, lead,
+                                                          l, r, gw)})
             grads = _zero_probes(grads)
             b1, b2, eps = opt["b1"], opt["b2"], opt["eps"]
             t = (count + 1).astype(F32)

@@ -9,6 +9,11 @@
 line of its plane); ``host`` holds the benchmark's own spans
 (``HOST_SPANS``).  Both are on the profiler's one clock.  The same lists,
 saved as JSON, are the recorded trace the tests read.
+
+An op event names its instruction, not the named scopes it ran under;
+those are in the ``op_name`` metadata of the instruction in the compiled
+program's text.  ``op_names`` reads them from that text and
+``stage_seconds`` adds up each stage's device time by them.
 """
 from __future__ import annotations
 
@@ -33,6 +38,20 @@ CONTAINERS = ("while", "conditional", "call")
 # tell the SMW kernel from the precondition's matmuls by this signature.
 SMW_OPERANDS = re.compile(r"custom-call\(.*?f32\[[\d,]+,1\].*?"
                           r"f32\[[\d,]+,1\]")
+# The training step's stages: the names of the program's named scopes
+# (its scopes.STAGES, copied here: the benchmark imports nothing of the
+# program), the backward pass (a forward op under ``transpose(``) and ops
+# under none of them.
+FORWARD = "forward"
+STAGES = (FORWARD, "mkor_stats", "mkor_smw", "mkor_precondition",
+          "backend", "apply", "grad_allreduce", "stat_allreduce",
+          "owner_gather")
+BACKWARD = "backward"
+UNSCOPED = "unscoped"
+SCOPE_WORD = re.compile(r"[^/()]+")
+# an instruction of the compiled text and the op_name of its metadata
+INSTR = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*?op_name="([^"]*)"',
+                   re.M)
 
 Events = Dict[str, object]
 
@@ -174,3 +193,47 @@ def breakdown(events: Events, device: str = "0", top: int = 10) -> Dict:
         named.append([f"idle during {host[-1] if host else 'no span'}",
                       (e - s) * 1e-9])
     return {"device_ops": [[n, v] for n, v in ops], "idle_gaps": named}
+
+
+# -------------------------------------------------------------------- #
+def op_names(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: its op_name} of a compiled program's text."""
+    return dict(INSTR.findall(hlo_text))
+
+
+def stage_of(path: str) -> str:
+    """The stage of an op by its ``op_name``: the innermost of ``STAGES``
+    among its path's words, ``backward`` for a forward op under
+    ``transpose(``, ``unscoped`` for none."""
+    words = SCOPE_WORD.findall(path)
+    for i in range(len(words) - 1, -1, -1):
+        if words[i] in STAGES:
+            if words[i] == FORWARD and "transpose" in words[:i]:
+                return BACKWARD
+            return words[i]
+    return UNSCOPED
+
+
+def stage_seconds(events: Events, names: Dict[str, str],
+                  device: str = "0") -> Dict[str, float]:
+    """Device seconds of each stage on ``device``: every op by the
+    ``op_name`` of its instruction in ``names`` (``op_names``); loops and
+    calls are left out and their bodies counted."""
+    out: Dict[str, float] = {}
+    for n, _, d in events["devices"][device]:
+        name, kind = op_name(n)
+        if kind in CONTAINERS:
+            continue
+        stage = stage_of(names.get(name, ""))
+        out[stage] = out.get(stage, 0.0) + d * 1e-9
+    return out
+
+
+def stage_share(stages: Dict, names: Sequence[str]):
+    """Percent of device busy time in the stages ``names`` of ``stages``
+    ({"seconds": stage_seconds(...), "busy_s": ...}); None where none of
+    them ran."""
+    s = sum(stages["seconds"].get(n, 0.0) for n in names)
+    if s <= 0 or stages["busy_s"] <= 0:
+        return None
+    return 100.0 * s / stages["busy_s"]
