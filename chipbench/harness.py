@@ -371,8 +371,28 @@ class CompileCounter:
 
 
 def state_bytes(jax, opt, params) -> int:
-    shapes = jax.eval_shape(opt.init, params)
-    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+    return tree_bytes(jax, jax.eval_shape(opt.init, params))
+
+
+def tree_bytes(jax, tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def memory_line(run: Run, opt, params0_host) -> str:
+    """The state the plain reference holds on the chip beside the
+    program's, from shapes alone (``jax.eval_shape``): weights as stored,
+    then its float32 moments and factors against the optimizer state."""
+    import functools
+
+    from reference import mkor_lamb
+    jax = run.jax
+    weights = tree_bytes(jax, params0_host)
+    ref = tree_bytes(jax, jax.eval_shape(functools.partial(
+        mkor_lamb.init_state, opt=run.opt_spec), params0_host))
+    prog = state_bytes(jax, opt, params0_host)
+    return (f"state_bytes reference={weights + ref} (weights {weights}, "
+            f"m v factors {ref}) program={weights + prog} (weights "
+            f"{weights}, optimizer state {prog})")
 
 
 def peak_bytes(jax) -> int:
@@ -458,6 +478,7 @@ def execute(cell: Dict, seed: int, seconds: float, trace: bool, t0: float,
     drv.params = drv.opt_state = None
     del drv, runner
 
+    log(memory_line(run, opt, params0))
     if control:
         prog = reference_numbers(run, params0, run.pool()[:run.chunk],
                                  control=control)
@@ -505,6 +526,38 @@ def kernel_work(run: Run, mcfg, params, count0: int, n_steps: int):
                     smw[0] += f
                     smw[1] += by
     return {"smw": tuple(smw), "precond": tuple(pre)}
+
+
+def reader_context(run: Run, events, names: Dict[str, str],
+                   **readings) -> Dict:
+    """What each per-layer metric's reader gets: the traced window's
+    ``events`` and the compiled chunk's ``op_names`` (``names``), each
+    stage's device seconds with device 0's busy seconds (``stages``), the
+    cell (``cell``: its name and chips; ``sz``: ``sizes`` of its
+    configuration; ``load``: its traffic file), and the run's own
+    ``readings``."""
+    import tracefile
+    return {
+        "events": events, "op_names": names, "chips": run.chips,
+        "cell": {"name": run.cell["name"], "chips": run.chips},
+        "sz": run.sz, "load": run.load,
+        "stages": {"seconds": tracefile.stage_seconds(events, names),
+                   "busy_s": tracefile.length(tracefile.busy(events, "0"))
+                   * 1e-9},
+        **readings}
+
+
+def read_metrics(per_layer, ctx: Dict) -> Dict:
+    """Each metric of ``per_layer`` by its reader, ``metrics/<name>.py``;
+    a reader that finds nothing to read returns None, and its metric is
+    left out."""
+    metrics = {}
+    for m in per_layer:
+        mod = load_module(BENCH / "metrics" / f"{m['name']}.py")
+        value = mod.read(ctx)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    return metrics
 
 
 def traced(run: Run, drv: Driver, opt, mcfg, tokens_per_chunk, counter,
@@ -559,28 +612,19 @@ def traced(run: Run, drv: Driver, opt, mcfg, tokens_per_chunk, counter,
     drv.steps, drv.failed = steps, failed
     names = tracefile.op_names(compiled.as_text())
 
-    ctx = {
-        "events": events, "chips": run.chips,
-        "flops_per_token": reference_model(run.cell).flops_per_token(
+    ctx = reader_context(
+        run, events, names,
+        flops_per_token=reference_model(run.cell).flops_per_token(
             run.sz, run.seq),
-        "tokens": n_trace * tokens_per_chunk,
-        "peaks": counts.peaks(jax.devices()[0].device_kind),
-        "work": work, "log": log,
-        "mkor_chunk_s": mkor_chunk_s, "lamb_chunk_s": lamb_chunk_s,
-        "mkor_state_bytes": mkor_state, "lamb_state_bytes": lamb_state,
-        "stages": {"seconds": tracefile.stage_seconds(events, names),
-                   "busy_s": tracefile.length(tracefile.busy(events, "0"))
-                   * 1e-9},
-        "step_metrics": {k: np.concatenate([np.ravel(m[k])
-                                            for m in step_metrics])
-                         for k in step_metrics[0]},
-    }
-    metrics = {}
-    for m in run.cell["per_layer"]:
-        mod = load_module(BENCH / "metrics" / f"{m['name']}.py")
-        value = mod.read(ctx)
-        if value is not None:
-            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        tokens=n_trace * tokens_per_chunk,
+        peaks=counts.peaks(jax.devices()[0].device_kind),
+        work=work, log=log,
+        mkor_chunk_s=mkor_chunk_s, lamb_chunk_s=lamb_chunk_s,
+        mkor_state_bytes=mkor_state, lamb_state_bytes=lamb_state,
+        step_metrics={k: np.concatenate([np.ravel(m[k])
+                                         for m in step_metrics])
+                      for k in step_metrics[0]})
+    metrics = read_metrics(run.cell["per_layer"], ctx)
     log(f"trace window_host_s={window_host_s:.3f} mkor_chunk_s="
         f"{mkor_chunk_s:.4f} lamb_chunk_s={lamb_chunk_s:.4f}")
     busy, window = tracefile.busy_and_window(events)

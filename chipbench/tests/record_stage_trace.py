@@ -5,8 +5,10 @@ the compiled chunk's text beside its trace.
     python3 chipbench/tests/record_stage_trace.py \
         [--out chipbench/tests/data/rwkv6-3b.small.stages.json.gz]
 
-The file holds ``tracefile.load``'s event lists and ``"hlo"``, the text.
-Needs a TPU.
+The file holds ``tracefile.load``'s event lists, ``"hlo"``, the text,
+and ``"ctx"``, what the kernel readers take besides them: the traced
+chunk's kernel work (``harness.kernel_work``), the chip's peaks and the
+configuration's sizes (``harness.sizes``).  Needs a TPU.
 """
 import argparse
 import gzip
@@ -28,6 +30,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     os.environ.setdefault("JAX_PLATFORMS", "tpu")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import counts
     import harness
     import tracefile
     from repro.training import loop
@@ -40,7 +43,7 @@ def main(argv=None):
     cell["load"]["optimizer"]["inv_freq"] = 2
     run = harness.Run(cell, 2 ** 31 + 1)
     jax = run.jax
-    opt, _ = run.optimizer()
+    opt, mcfg = run.optimizer()
     params = run.weights()
     drv = harness.Driver(run, run.runner(opt), params,
                          jax.jit(opt.init)(params), run.pool())
@@ -49,6 +52,10 @@ def main(argv=None):
     drv.chunk()
     text = drv.runner.lower(drv.params, drv.opt_state, loop.stack_batches(
         drv.pool[:run.chunk])).compile().as_text()
+    ctx = {"work": harness.kernel_work(run, mcfg, drv.params,
+                                       drv.next * run.chunk, run.chunk),
+           "peaks": counts.peaks(jax.devices()[0].device_kind),
+           "sz": run.sz}
     trace_dir = harness.TRACE_DIR
     shutil.rmtree(trace_dir, ignore_errors=True)
     jax.profiler.start_trace(str(trace_dir))
@@ -58,10 +65,11 @@ def main(argv=None):
     shutil.rmtree(trace_dir, ignore_errors=True)
     Path(args.out).parent.mkdir(parents=True, exist_ok=True)
     with gzip.open(args.out, "wt") as f:
-        json.dump({**events, "hlo": text}, f)
+        json.dump({**events, "hlo": text, "ctx": ctx}, f)
     stages = tracefile.stage_seconds(events, tracefile.op_names(text))
     print(json.dumps({"out": args.out, "ops": len(events["devices"]["0"]),
-                      "hlo_chars": len(text), "stages": stages}))
+                      "hlo_chars": len(text), "stages": stages,
+                      "work": ctx["work"]}))
 
 
 if __name__ == "__main__":

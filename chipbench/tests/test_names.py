@@ -1,6 +1,7 @@
-"""The benchmark's names for the chunk loop's host spans and for the
-training step's stages are the program's own (src/repro/scopes.py), kept
-as copies: the benchmark imports nothing of the program."""
+"""The benchmark's names for the chunk loop's host spans, the training
+step's stages and the Pallas kernels are the program's own
+(src/repro/scopes.py), kept as copies: the benchmark imports nothing of
+the program."""
 import pytest
 
 import harness
@@ -16,6 +17,13 @@ def test_stage_names_are_the_programs():
     assert tracefile.STAGES == scopes.STAGES
     assert (tracefile.FORWARD, tracefile.BACKWARD) == \
         (scopes.FORWARD, scopes.BACKWARD)
+
+
+def test_kernel_names_are_the_programs():
+    assert (tracefile.SMW_KERNEL, tracefile.BLOCK_SMW_KERNEL,
+            tracefile.PRECOND_KERNEL, tracefile.MATMUL_KERNEL) == \
+        (scopes.SMW_KERNEL, scopes.BLOCK_SMW_KERNEL, scopes.PRECOND_KERNEL,
+         scopes.MATMUL_KERNEL)
 
 
 @pytest.mark.parametrize("path", [

@@ -25,10 +25,10 @@ def test_interval_algebra():
     assert tracefile.clip([[0, 4], [6, 9]], 2, 7) == [[2, 4], [6, 7]]
 
 
-SMW = ('%vmap__.9 = bf16[2,256,256]{2,1,0} custom-call(bf16[2,256,256]'
+SMW = ('%vmap_mkor_smw_.9 = bf16[2,256,256]{2,1,0} custom-call(bf16[2,256,256]'
        '{2,1,0} %g.1, f32[2,256,1]{2,1,0} %g.2, f32[2,256,1]{2,1,0} %g.2), '
        'custom_call_target="tpu_custom_call"')
-MATMUL = ('%vmap__.8 = f32[2,256,64]{2,1,0} custom-call(bf16[2,256,256]'
+MATMUL = ('%vmap_mkor_matmul_.8 = f32[2,256,64]{2,1,0} custom-call(bf16[2,256,256]'
           '{2,1,0} %g.1, bf16[2,256,64]{2,1,0} %g.3), '
           'custom_call_target="tpu_custom_call"')
 FUSION = "%fusion.1 = f32[4]{0} fusion(f32[4]{0} %p.1), kind=kLoop"
@@ -45,14 +45,17 @@ def test_busy_idle_and_gaps_by_hand():
     assert busy == pytest.approx(50e-9)            # [10, 50) and [70, 80)
     gaps = tracefile.breakdown(ev)["idle_gaps"]
     assert gaps[0] == ["idle during device_get", pytest.approx(20e-9)]
-    assert tracefile.pallas_seconds(ev, "0", smw=True) == \
+    # kernels by their instructions' names where no text is at hand
+    assert tracefile.kernel_seconds(ev, {}, (tracefile.SMW_KERNEL,)) == \
         pytest.approx(10e-9)
-    assert tracefile.pallas_seconds(ev, "0", smw=False) == \
+    assert tracefile.kernel_seconds(ev, {}, (tracefile.PRECOND_KERNEL,
+                                             tracefile.MATMUL_KERNEL)) == \
         pytest.approx(30e-9)
     ops = dict(tracefile.breakdown(ev)["device_ops"])
     assert ops == {"fusion.1 (fusion)": pytest.approx(30e-9),
-                   "vmap__.8 (tpu_custom_call)": pytest.approx(30e-9),
-                   "vmap__.9 (tpu_custom_call)": pytest.approx(10e-9)}
+                   "vmap_mkor_matmul_.8 (tpu_custom_call)":
+                       pytest.approx(30e-9),
+                   "vmap_mkor_smw_.9 (tpu_custom_call)": pytest.approx(10e-9)}
 
 
 def recorded():
@@ -75,8 +78,13 @@ def test_recorded_trace_reduces():
                                     "smw_roofline_pct",
                                     "precond_roofline_pct"])
 def test_recorded_trace_metrics_are_shares(metric):
-    ev = recorded()
-    ctx = dict(ev["ctx"], events=ev, log=lambda m: None)
+    """The whole-window metrics on the full-width trace; the kernels'
+    rooflines on the small one, whose kernels carry their names."""
+    if metric.endswith("_roofline_pct"):
+        ev, names = staged()
+    else:
+        ev, names = recorded(), {}
+    ctx = dict(ev["ctx"], events=ev, op_names=names, log=lambda m: None)
     mod = harness.load_module(harness.BENCH / "metrics" / f"{metric}.py")
     value = mod.read(ctx)
     assert value is not None and 0 < value <= 100, value
@@ -89,8 +97,8 @@ HloModule jit_run_chunk
 %body.1 (p: f32[4]) -> f32[4] {
   %fusion.1 = f32[4]{0} fusion(f32[4]{0} %p.1), kind=kLoop, metadata={op_name="jit(run_chunk)/while/body/forward/dot_general" source_file="x.py" source_line=3}
   %fusion.2 = f32[4]{0} fusion(f32[4]{0} %p.1), kind=kLoop, metadata={op_name="jit(run_chunk)/while/body/transpose(jvp(forward))/mul"}
-  %vmap__.8 = f32[2,256,64]{2,1,0} custom-call(bf16[2,256,256]{2,1,0} %g.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(run_chunk)/while/body/mkor_precondition/vmap(mkor_matmul)"}
-  %vmap__.9 = bf16[2,256,256]{2,1,0} custom-call(bf16[2,256,256]{2,1,0} %g.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(run_chunk)/while/body/cond/branch_1_fun/mkor_smw/vmap(mkor_smw)"}
+  %vmap_mkor_matmul_.8 = f32[2,256,64]{2,1,0} custom-call(bf16[2,256,256]{2,1,0} %g.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(run_chunk)/while/body/mkor_precondition/vmap(mkor_matmul)"}
+  %vmap_mkor_smw_.9 = bf16[2,256,256]{2,1,0} custom-call(bf16[2,256,256]{2,1,0} %g.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(run_chunk)/while/body/cond/branch_1_fun/mkor_smw/vmap(mkor_smw)"}
   %copy.3 = f32[4]{0} copy(f32[4]{0} %p.1)
   ROOT %add.4 = f32[4]{0} add(f32[4]{0} %p.1, f32[4]{0} %p.1), metadata={op_name="jit(run_chunk)/while/body/backend/mkor_stats/add"}
 }
@@ -127,13 +135,141 @@ def test_stage_attribution_by_hand():
     assert tracefile.stage_share(stages, ("backend",)) is None
 
 
+def line(text, instruction):
+    """An op event's name: its instruction's HLO text, without metadata."""
+    for ln in text.splitlines():
+        if f"%{instruction} = " in ln:
+            return ln.split(", metadata=")[0].replace("ROOT ", "").strip()
+    raise KeyError(instruction)
+
+
+# A scope that is no stage (a model's attention) forward and backward, and
+# a Pallas kernel of its own whose operands include two f32[..., 1], as
+# the SMW kernel's do.
+ATTN_TEXT = '''
+HloModule jit_run_chunk
+ENTRY %main.9 (p: f32[4]) -> f32[4] {
+  %fusion.5 = f32[4]{0} fusion(f32[4]{0} %p.1), kind=kLoop, metadata={op_name="jit(run_chunk)/while/body/forward/attention/dot_general"}
+  %fusion.6 = f32[4]{0} fusion(f32[4]{0} %p.1), kind=kLoop, metadata={op_name="jit(run_chunk)/while/body/transpose(jvp(forward))/attention/dot_general"}
+  %vmap_flash_fwd_.7 = f32[2,256,64]{2,1,0} custom-call(bf16[2,256,256]{2,1,0} %g.1, f32[2,256,1]{2,1,0} %g.2, f32[2,256,1]{2,1,0} %g.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(run_chunk)/while/body/forward/attention/vmap(flash_fwd)/pallas_call"}
+  %fusion.8 = f32[4]{0} fusion(f32[4]{0} %p.1), kind=kLoop, metadata={op_name="jit(run_chunk)/while/body/forward/mlp/mul"}
+  %vmap_mkor_smw_.9 = bf16[2,256,256]{2,1,0} custom-call(bf16[2,256,256]{2,1,0} %g.1, f32[2,256,1]{2,1,0} %g.2, f32[2,256,1]{2,1,0} %g.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(run_chunk)/while/body/cond/branch_1_fun/mkor_smw/vmap(mkor_smw)/pallas_call"}
+  ROOT %copy.10 = f32[4]{0} copy(f32[4]{0} %p.1)
+}
+'''
+ATTN_OPS = [("fusion.5", 0, 10), ("fusion.6", 10, 20),
+            ("vmap_flash_fwd_.7", 30, 40), ("fusion.8", 70, 7),
+            ("vmap_mkor_smw_.9", 77, 5), ("copy.10", 82, 3)]
+
+
+def attention_events():
+    return events([(line(ATTN_TEXT, n), s, d) for n, s, d in ATTN_OPS])
+
+
+def test_scope_outside_the_stages_by_hand():
+    ev, names = attention_events(), tracefile.op_names(ATTN_TEXT)
+    got = tracefile.scope_seconds(ev, names, ("attention",))
+    assert got == {"attention": pytest.approx(50e-9),
+                   tracefile.backward_part("attention"): pytest.approx(20e-9),
+                   tracefile.UNSCOPED: pytest.approx(15e-9)}
+    # the stages do not see the new scope: its ops keep the forward pass's
+    # stage and its backward's
+    assert tracefile.stage_seconds(ev, names) == {
+        "forward": pytest.approx(57e-9), "backward": pytest.approx(20e-9),
+        "mkor_smw": pytest.approx(5e-9),
+        tracefile.UNSCOPED: pytest.approx(3e-9)}
+    ctx = {"events": ev, "op_names": names, "stages": {"busy_s": 85e-9}}
+    assert tracefile.scope_share(ctx, ("attention",)) == \
+        pytest.approx(100 * 70 / 85)
+    assert tracefile.scope_share(ctx, ("router",)) is None
+
+
+def test_a_kernel_of_its_own_is_in_neither_mkor_reader():
+    ev, names = attention_events(), tracefile.op_names(ATTN_TEXT)
+    assert tracefile.kernel_seconds(ev, names, ("flash_fwd",)) == \
+        pytest.approx(40e-9)
+    peaks = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e9}
+    ctx = {"events": ev, "op_names": names, "peaks": peaks,
+           "work": {"smw": (0.0, 1.0), "precond": (0.0, 1.0)},
+           "log": lambda m: None}
+    smw = harness.load_module(harness.BENCH / "metrics" /
+                              "smw_roofline_pct.py")
+    precond = harness.load_module(harness.BENCH / "metrics" /
+                                  "precond_roofline_pct.py")
+    # the SMW reader times the mkor_smw kernel alone: 1 ns least, 5 ns run
+    assert smw.read(ctx) == pytest.approx(20.0)
+    assert precond.read(ctx) is None
+
+
+@pytest.mark.parametrize("instruction,path,kernel", [
+    ("vmap_mkor_smw_.11", "jit(run_chunk)/while/body/closed_call/cond/"
+     "branch_1_fun/mkor_smw/vmap(mkor_smw)/pallas_call", "mkor_smw"),
+    ("vmap_mkor_matmul_.8",
+     "jit(run_chunk)/while/body/mkor_precondition/vmap(mkor_matmul)",
+     "mkor_matmul"),
+    ("x.1", "jit(f)/vmap(vmap(flash_fwd))/pallas_call", "flash_fwd"),
+    ("vmap_mkor_matmul_.76", "", "mkor_matmul"),
+    ("vmap_vmap_mkor_precond__.3", "", "mkor_precond"),
+    ("mkor_block_smw.2", "", "mkor_block_smw"),
+    # a kernel given no name, in the mkor_smw scope: no kernel's name
+    ("vmap__.88", "jit(run_chunk)/while/body/mkor_smw/vmap()/pallas_call",
+     ""),
+    ("vmap__.88", "", "")])
+def test_kernel_of(instruction, path, kernel):
+    assert tracefile.kernel_of(instruction, path) == kernel
+
+
+def test_reader_added_as_a_file(tmp_path, monkeypatch, data_cell):
+    """A configuration's readers come as new files of metrics/ alone: a
+    scope that is no stage, its own kernel's time, and the cell's sizes
+    and traffic, all from the context the harness builds."""
+    metrics = tmp_path / "metrics"
+    metrics.mkdir()
+    (metrics / "attention_device_pct.py").write_text(
+        "import tracefile\n\n\ndef read(ctx):\n"
+        "    return tracefile.scope_share(ctx, ('attention',))\n")
+    (metrics / "router_device_pct.py").write_text(
+        "import tracefile\n\n\ndef read(ctx):\n"
+        "    return tracefile.scope_share(ctx, ('router',))\n")
+    (metrics / "flash_fwd_roofline_pct.py").write_text(
+        "import counts\nimport tracefile\n\n\ndef read(ctx):\n"
+        "    sz, load = ctx['sz'], ctx['load']\n"
+        "    t = tracefile.kernel_seconds(ctx['events'], ctx['op_names'],\n"
+        "                                 ('flash_fwd',))\n"
+        "    rows = load['batch_per_chip'] * ctx['cell']['chips']\n"
+        "    flops = 4.0 * rows * load['seq_len'] ** 2 * sz['n_heads'] \\\n"
+        "        * sz['head_dim']\n"
+        "    least, _ = counts.least_time(flops, 0.0, ctx['peaks'])\n"
+        "    return 100.0 * least / t\n")
+    cell = data_cell("qwen2-moe-a2.7b.small")
+    run = harness.Run(cell, 3)
+    peaks = {"bf16_flops": 1e21, "hbm_bytes_per_s": 1e12}
+    ctx = harness.reader_context(run, attention_events(),
+                                 tracefile.op_names(ATTN_TEXT), peaks=peaks)
+    monkeypatch.setattr(harness, "BENCH", tmp_path)
+    got = harness.read_metrics(
+        [{"name": n, "unit": "%"} for n in ("attention_device_pct",
+                                             "router_device_pct",
+                                             "flash_fwd_roofline_pct")],
+        ctx)
+    sz = harness.sizes(cell["model"])
+    flops = 4.0 * 2 * 16 ** 2 * sz["n_heads"] * sz["head_dim"]
+    assert got == {
+        "attention_device_pct": {"value": pytest.approx(100 * 70 / 85),
+                                 "unit": "%"},
+        "flash_fwd_roofline_pct": {
+            "value": pytest.approx(100 * flops / 1e21 / 40e-9), "unit": "%"}}
+    assert ctx["cell"] == {"name": cell["name"], "chips": 1}
+
+
 STAGED = DATA / "rwkv6-3b.small.stages.json.gz"
 
 
 def staged():
     """One chunk of two steps of the cell's program at width 256, traced
-    on a TPU v5e, with the compiled chunk's text (record_stage_trace.py);
-    inv_freq 2, so that each of its three buckets takes its phase step."""
+    on a TPU v5e, with the compiled chunk's text and the kernel readers'
+    context (record_stage_trace.py); inv_freq 2, so that each of its
+    three buckets takes its phase step."""
     if not STAGED.exists():
         pytest.fail(f"recorded trace {STAGED.name} is missing")
     ev = tracefile.read(STAGED)
@@ -178,3 +314,28 @@ def test_recorded_stage_shares(metric):
     value = mod.read({"stages": stages})
     assert value is not None and 0 < value <= 100, value
     assert 100 * sum(stages["seconds"].values()) / stages["busy_s"] <= 100
+
+
+@pytest.mark.parametrize("recording", ["full-width", "small"])
+def test_scope_seconds_over_the_stages_is_stage_seconds(recording):
+    ev, names = staged() if recording == "small" else (recorded(), {})
+    scopes = tracefile.scope_seconds(ev, names, tracefile.STAGES)
+    stages = tracefile.stage_seconds(ev, names)
+    back = tracefile.backward_part(tracefile.FORWARD)
+    assert {tracefile.BACKWARD if k == back else k: v
+            for k, v in scopes.items()} == stages
+
+
+def test_recorded_kernels_by_name():
+    """Every Pallas call of the small trace is one of the program's named
+    kernels, found alike by op_name and by instruction name."""
+    ev, names = staged()
+    calls = [tracefile.op_name(n)[0] for n, _, _ in ev["devices"]["0"]
+             if tracefile.PALLAS in n]
+    assert sorted({tracefile.kernel_of(c, names[c]) for c in calls}) == \
+        [tracefile.PRECOND_KERNEL, tracefile.SMW_KERNEL]
+    for kernels in ((tracefile.SMW_KERNEL,), (tracefile.PRECOND_KERNEL,
+                                              tracefile.MATMUL_KERNEL)):
+        by_name = tracefile.kernel_seconds(ev, names, kernels)
+        assert by_name > 0
+        assert tracefile.kernel_seconds(ev, {}, kernels) == by_name

@@ -12,8 +12,10 @@ saved as JSON, are the recorded trace the tests read.
 
 An op event names its instruction, not the named scopes it ran under;
 those are in the ``op_name`` metadata of the instruction in the compiled
-program's text.  ``op_names`` reads them from that text and
-``stage_seconds`` adds up each stage's device time by them.
+program's text.  ``op_names`` reads them from that text;
+``scope_seconds`` adds up the device time under any named scopes by them
+(``stage_seconds``: the training step's stages), and ``kernel_seconds``
+that of Pallas kernels by their names.
 """
 from __future__ import annotations
 
@@ -22,7 +24,7 @@ import gzip
 import json
 import re
 from pathlib import Path
-from typing import Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence, Tuple
 
 HOST_SPANS = ("stack_batches", "dispatch", "device_get")
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -33,11 +35,16 @@ OPS_LINE = "XLA Ops"
 HLO = re.compile(r"%?([\w.\-]+) = .*? ([\w\-]+)\(")
 PALLAS = 'custom_call_target="tpu_custom_call"'
 CONTAINERS = ("while", "conditional", "call")
-# The fused SMW kernel's operands: the bank, then the vector twice, (d, 1)
-# in float32.  The program names no kernel in the trace, so the readers
-# tell the SMW kernel from the precondition's matmuls by this signature.
-SMW_OPERANDS = re.compile(r"custom-call\(.*?f32\[[\d,]+,1\].*?"
-                          r"f32\[[\d,]+,1\]")
+# The program's Pallas kernels, by the names it gives them
+# (``pl.pallas_call(name=...)``; its scopes.*_KERNEL, copied here).
+SMW_KERNEL = "mkor_smw"
+BLOCK_SMW_KERNEL = "mkor_block_smw"
+PRECOND_KERNEL = "mkor_precond"
+MATMUL_KERNEL = "mkor_matmul"
+# JAX's transforms, as an instruction's name spells them around a
+# kernel's name ("vmap(mkor_matmul)" becomes "vmap_mkor_matmul_.76").
+TRANSFORM_PREFIX = re.compile(r"^(?:(?:vmap|jvp|transpose|remat|checkpoint)"
+                              r"_)+")
 # The training step's stages: the names of the program's named scopes
 # (its scopes.STAGES, copied here: the benchmark imports nothing of the
 # program), the backward pass (a forward op under ``transpose(``) and ops
@@ -154,14 +161,6 @@ def device_span(events: Events, device: str) -> float:
     return (max(s + d for _, s, d in ops) - min(s for _, s, _ in ops)) * 1e-9
 
 
-def pallas_seconds(events: Events, device: str, smw: bool) -> float:
-    """Summed duration of the Pallas kernels that are (``smw``) or are not
-    the fused SMW kernel."""
-    return sum(d for n, _, d in events["devices"][device]
-               if PALLAS in n
-               and (SMW_OPERANDS.search(n) is not None) == smw) * 1e-9
-
-
 def op_name(event_name: str) -> Tuple[str, str]:
     """(instruction name, kind) of an operation's HLO text; Pallas kernels
     have the kind "tpu_custom_call"."""
@@ -201,32 +200,62 @@ def op_names(hlo_text: str) -> Dict[str, str]:
     return dict(INSTR.findall(hlo_text))
 
 
-def stage_of(path: str) -> str:
-    """The stage of an op by its ``op_name``: the innermost of ``STAGES``
-    among its path's words, ``backward`` for a forward op under
-    ``transpose(``, ``unscoped`` for none."""
-    words = SCOPE_WORD.findall(path)
-    for i in range(len(words) - 1, -1, -1):
-        if words[i] in STAGES:
-            if words[i] == FORWARD and "transpose" in words[:i]:
-                return BACKWARD
-            return words[i]
+def scope_of(path: str, words: Sequence[str]) -> str:
+    """The scope of an op by its ``op_name``: the innermost of ``words``
+    among its path's words, ``backward_part`` of it where ``transpose``
+    comes before it, ``unscoped`` for none."""
+    found = SCOPE_WORD.findall(path)
+    for i in range(len(found) - 1, -1, -1):
+        if found[i] in words:
+            if "transpose" in found[:i]:
+                return backward_part(found[i])
+            return found[i]
     return UNSCOPED
 
 
-def stage_seconds(events: Events, names: Dict[str, str],
-                  device: str = "0") -> Dict[str, float]:
-    """Device seconds of each stage on ``device``: every op by the
-    ``op_name`` of its instruction in ``names`` (``op_names``); loops and
-    calls are left out and their bodies counted."""
+def backward_part(word: str) -> str:
+    """The key of the time under ``word`` in the backward pass."""
+    return f"{word}.{BACKWARD}"
+
+
+def stage_of(path: str) -> str:
+    """The stage of an op by its ``op_name``: ``scope_of`` over
+    ``STAGES``, where only the forward scope has a backward part
+    (``backward``); the other stages keep their ops under ``transpose(``."""
+    scope = scope_of(path, STAGES)
+    if scope == backward_part(FORWARD):
+        return BACKWARD
+    return scope.split(".")[0]
+
+
+def _seconds(events: Events, names: Dict[str, str], device: str,
+             key: Callable[[str], str]) -> Dict[str, float]:
+    """Device seconds on ``device`` by ``key`` of each op's ``op_name``
+    in ``names``; loops and calls are left out and their bodies counted."""
     out: Dict[str, float] = {}
     for n, _, d in events["devices"][device]:
         name, kind = op_name(n)
         if kind in CONTAINERS:
             continue
-        stage = stage_of(names.get(name, ""))
-        out[stage] = out.get(stage, 0.0) + d * 1e-9
+        k = key(names.get(name, ""))
+        out[k] = out.get(k, 0.0) + d * 1e-9
     return out
+
+
+def scope_seconds(events: Events, names: Dict[str, str],
+                  words: Sequence[str], device: str = "0"
+                  ) -> Dict[str, float]:
+    """Device seconds on ``device`` under each of the named scopes
+    ``words``, each op counted once by ``scope_of`` the ``op_name`` of its
+    instruction in ``names`` (``op_names``): keys are the words, their
+    ``backward_part``s and ``unscoped``."""
+    return _seconds(events, names, device, lambda p: scope_of(p, words))
+
+
+def stage_seconds(events: Events, names: Dict[str, str],
+                  device: str = "0") -> Dict[str, float]:
+    """Device seconds of each stage (``stage_of``) on ``device``."""
+    return _seconds(events, names, device, stage_of)
 
 
 def stage_share(stages: Dict, names: Sequence[str]):
@@ -237,3 +266,43 @@ def stage_share(stages: Dict, names: Sequence[str]):
     if s <= 0 or stages["busy_s"] <= 0:
         return None
     return 100.0 * s / stages["busy_s"]
+
+
+def scope_share(ctx: Dict, words: Sequence[str]):
+    """Percent of device 0's busy time under the named scopes ``words``,
+    forward and backward parts together, in a traced run's ``ctx``; None
+    where none of them ran."""
+    seconds = scope_seconds(ctx["events"], ctx["op_names"], words)
+    return stage_share({"seconds": seconds,
+                        "busy_s": ctx["stages"]["busy_s"]},
+                       [k for w in words for k in (w, backward_part(w))])
+
+
+def kernel_of(instruction: str, path: str) -> str:
+    """The name of a Pallas kernel: the innermost component of its
+    ``op_name`` before ``pallas_call``, JAX's transforms around it
+    stripped ("…/mkor_smw/vmap(mkor_smw)/pallas_call": "mkor_smw"); where
+    the text gives no ``op_name``, its instruction's name without them and
+    without its number ("vmap_mkor_matmul_.76": "mkor_matmul").  "" for
+    a kernel that was given no name ("vmap()", "vmap__.88")."""
+    if path:
+        part = re.sub(r"/pallas_call$", "", path).rsplit("/", 1)[-1]
+        while "(" in part:
+            part = part[part.index("(") + 1:part.rindex(")")]
+        return part
+    stem = re.sub(r"\.\d+$", "", instruction)
+    return TRANSFORM_PREFIX.sub("", stem).rstrip("_")
+
+
+def kernel_seconds(events: Events, names: Dict[str, str],
+                   kernels: Sequence[str], device: str = "0") -> float:
+    """Summed device seconds on ``device`` of the Pallas kernels named in
+    ``kernels`` (``kernel_of``, by ``names``: ``op_names``)."""
+    total = 0.0
+    for n, _, d in events["devices"][device]:
+        if PALLAS not in n:
+            continue
+        name = op_name(n)[0]
+        if kernel_of(name, names.get(name, "")) in kernels:
+            total += d
+    return total * 1e-9
