@@ -1,8 +1,12 @@
 """Attention-free sequence mixers: RWKV-6 ("Finch") and Mamba (for Jamba).
 
-Both are implemented with a `lax.scan` over time for training/prefill and an
-O(1)-state single-step path for decode — this is what makes `long_500k`
-(524288-token decode) tractable for the ssm/hybrid architectures.
+Both have an O(1)-state single-step path for decode — this is what makes
+`long_500k` (524288-token decode) tractable for the ssm/hybrid
+architectures.  For training and prefill Mamba runs a `lax.scan` over time;
+RWKV-6 runs its wkv recurrence a chunk of `WKV_CHUNK` tokens at a time
+(`_wkv_chunked`): dense f32 contractions within a chunk and a `lax.scan`
+that carries the (B, H, N, N) state across chunks, so neither the forward
+nor the backward pass loops over tokens or keeps a per-token state.
 
 RWKV-6 follows arXiv:2404.05892: token-shift with data-dependent ("ddlerp")
 mixing via a low-rank MLP, per-channel **data-dependent decay**
@@ -25,7 +29,9 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro import scopes
 from repro.models import layers
 from repro.models.config import ModelConfig
 
@@ -77,10 +83,11 @@ def rwkv_cm_init(key, cfg: ModelConfig, *, dtype) -> Dict:
     }
 
 
-def _rwkv_projections(p, x, x_prev, cfg, stats):
-    """Data-dependent token-shift mixing + r/k/v/g/w projections.
+def _rwkv_mix(p, x, x_prev, cfg, stats):
+    """Data-dependent token-shift mixing + r/k/v/g projections and decay.
 
-    x, x_prev: (B, S, d). Returns r,k,v,g heads (B,S,H,N) and decay w.
+    x, x_prev: (B, S, d). Returns r,k,v,g heads (B,S,H,N) and the log of
+    the decay, ``log w = -exp(w0 + tanh(x W1) W2)`` (B,S,H,N), <= 0.
     """
     d = cfg.d_model
     n = cfg.rwkv_head_dim
@@ -100,9 +107,14 @@ def _rwkv_projections(p, x, x_prev, cfg, stats):
     g = jax.nn.silu(layers.dense(p["g"], xg, stats=stats, name="g"))
     dec = p["decay_w0"] + jnp.tanh(xw.astype(jnp.float32) @ p["decay_w1"]) \
         @ p["decay_w2"]
-    w = jnp.exp(-jnp.exp(dec))                         # (B,S,d) in (0,1)
     hd = lambda t: t.reshape(*t.shape[:-1], h, n)
-    return hd(r), hd(k), hd(v), g, hd(w)
+    return hd(r), hd(k), hd(v), g, hd(-jnp.exp(dec))
+
+
+def _rwkv_projections(p, x, x_prev, cfg, stats):
+    """`_rwkv_mix` with the decay itself, w = exp(log w) in (0, 1]."""
+    r, k, v, g, lw = _rwkv_mix(p, x, x_prev, cfg, stats)
+    return r, k, v, g, jnp.exp(lw)
 
 
 def _wkv_step(state, rkvw, bonus):
@@ -114,6 +126,127 @@ def _wkv_step(state, rkvw, bonus):
     return state, y
 
 
+# The chunked wkv.  Within a chunk, with L the running sum of log w,
+#   y_t = r_t (e^{L_{t-1}} S_0 + sum_{s<t} e^{L_{t-1}-L_s} k_s v_s^T)
+#         + (r_t u k_t) v_t,
+#   S_C = e^{L_C} S_0 + sum_s e^{L_C-L_s} k_s v_s^T.
+# Every exponent taken is a sum of log w over a run of tokens, so it is
+# <= 0: a strong decay underflows to its true value (about 0), nothing
+# overflows, and no cumulative decay is divided by.  A pair (t, s) in
+# different sub-chunks splits its weight at the end of s's sub-chunk into
+# two such factors, one on r_t and one on k_s, and goes through a matmul;
+# their runs are prefix and suffix sums of their own, exact to a few ulp
+# however strong the decays before them.  Pairs within a sub-chunk get
+# their weight elementwise, masked to s < t, from the difference of two
+# running sums that start at the sub-chunk: it rounds like a sum of at
+# most WKV_SUB tokens.
+WKV_CHUNK = 64       # tokens a chunk: a one-layer timing on a v5e (PERF.md)
+WKV_SUB = 16         # tokens a sub-chunk
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _shift(t, axis, back=False):
+    """t moved one place along ``axis``, towards its end (its start where
+    ``back``), with a zero let in: an inclusive running sum made
+    exclusive."""
+    zero = jnp.zeros_like(jax.lax.slice_in_dim(t, 0, 1, axis=axis))
+    if back:
+        return jnp.concatenate(
+            [jax.lax.slice_in_dim(t, 1, None, axis=axis), zero], axis)
+    return jnp.concatenate(
+        [zero, jax.lax.slice_in_dim(t, 0, -1, axis=axis)], axis)
+
+
+def _wkv_within_chunks(r, k, v, lw, bonus):
+    """The wkv of each chunk on its own, from a zero state.
+
+    r, k, v, lw: (..., C, N) f32, lw = log w <= 0; bonus broadcasts to
+    them.  Returns ``(y, r_in, decay, kv)``: the chunk's read-out (...,C,N),
+    r weighted by the decay from the chunk's start to each token (the
+    read-out of the incoming state is ``r_in @ S_0``), the chunk's whole
+    decay (..., N), and what it adds to the state (..., N, N).
+    """
+    *lead, c, n = r.shape
+    e = WKV_SUB
+    m = c // e
+    sub = lambda t: t.reshape(*lead, m, e, n)
+    lws = sub(lw)
+    # running sums of log w within each sub-chunk: over the tokens before
+    # t, and over those after it
+    inc = jnp.cumsum(lws, axis=-2)
+    before = _shift(inc, -2)
+    after = _shift(jax.lax.cumsum(lws, axis=lws.ndim - 2, reverse=True), -2,
+                   back=True)
+    total = jnp.sum(lws, axis=-2)                       # (..., m, N)
+    ia = np.arange(m)
+    lt = ia[None, :] < ia[:, None]                      # [b, a]: a < b
+    btw = lt[:, :, None] & (ia[None, :, None] < ia[None, None, :]) \
+        & lt[:, None, :]                                # [b, a, a']: a<a'<b
+    tot = total[..., None, :, :]
+    sum_lt = jnp.sum(jnp.where(lt[:, :, None], tot, 0.0), -2)
+    sum_gt = jnp.sum(jnp.where(lt.T[:, :, None], tot, 0.0), -2)
+    sum_btw = jnp.sum(jnp.where(btw[..., None], tot[..., None, :, :], 0.0),
+                      -2)                               # (..., m, m, N)
+
+    r_in = (sub(r) * jnp.exp(before + sum_lt[..., None, :])).reshape(r.shape)
+    k_out = (sub(k) * jnp.exp(after + sum_gt[..., None, :])).reshape(k.shape)
+    decay = jnp.exp(jnp.sum(total, axis=-2))
+    kv = jnp.einsum("...si,...sj->...ij", k_out, v, precision=_HIGHEST)
+
+    # pairs in different sub-chunks: r_t decayed back to the end of s's
+    # sub-chunk a (zero where a is not before t's), k_s decayed to it
+    qx = before[..., :, :, None, :] + sum_btw[..., :, None, :, :]
+    q = sub(r)[..., None, :] * jnp.exp(
+        jnp.where(lt[:, None, :, None], qx, -jnp.inf))  # (..., m, e, m, N)
+    ks = sub(k) * jnp.exp(after)
+    att = jnp.einsum("...tai,...asi->...tas", q.reshape(*lead, c, m, n), ks,
+                     precision=_HIGHEST).reshape(*lead, m, e, m, e)
+    # pairs within a sub-chunk: the sum of log w strictly between s and t
+    tri = np.tri(e, k=-1, dtype=bool)                   # [t, s]: s < t
+    mid = before[..., :, None, :] - inc[..., None, :, :]
+    w_ts = jnp.exp(jnp.where(tri[:, :, None], mid, -jnp.inf))
+    diag = jnp.sum(sub(r)[..., :, None, :] * w_ts * sub(k)[..., None, :, :],
+                   -1)                                  # (..., m, e, e)
+    eye = np.eye(m, dtype=bool)[:, None, :, None]
+    att = jnp.where(eye, diag[..., :, :, None, :], att).reshape(*lead, c, c)
+    y = jnp.einsum("...ts,...sj->...tj", att, v, precision=_HIGHEST)
+    y = y + jnp.sum(r * bonus * k, -1, keepdims=True) * v
+    return y, r_in, decay, kv
+
+
+def _wkv_chunked(r, k, v, lw, bonus, state, *, chunk=WKV_CHUNK):
+    """The wkv recurrence of `_wkv_step` over a whole sequence, a chunk at
+    a time.
+
+    r, k, v, lw: (B,S,H,N) f32 with lw = log w; bonus (H,N); state
+    (B,H,N,N).  Returns (y (B,S,H,N), final state).  A sequence shorter
+    than ``chunk`` is one chunk of whole sub-chunks; the tail is padded
+    with r = k = v = 0 and log w = 0, which leaves the state and the
+    outputs kept as they are.
+    """
+    b, s, h, n = r.shape
+    c = min(chunk, -(-s // WKV_SUB) * WKV_SUB)
+    pad = -s % c
+    nc = (s + pad) // c
+
+    def chunks(t):                                      # -> (B,H,nc,C,N)
+        t = jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        return t.reshape(b, nc, c, h, n).transpose(0, 3, 1, 2, 4)
+
+    y, r_in, decay, kv = _wkv_within_chunks(
+        *map(chunks, (r, k, v, lw)), bonus[:, None, None, :])
+
+    def carry(st, dkv):
+        d, add = dkv
+        return d[..., None] * st + add, st
+
+    state, s_in = jax.lax.scan(carry, state, (jnp.moveaxis(decay, 2, 0),
+                                              jnp.moveaxis(kv, 2, 0)))
+    y = y + jnp.einsum("bhcti,cbhij->bhctj", r_in, s_in, precision=_HIGHEST)
+    y = y.transpose(0, 2, 3, 1, 4).reshape(b, nc * c, h, n)[:, :s]
+    return y, state
+
+
 def rwkv_time_mix(p, x, cfg, *, state=None, x_prev=None,
                   stats: Optional[dict] = None) -> Tuple[jnp.ndarray, Dict]:
     """Full-sequence RWKV-6 time mixing.  Returns (y, final_state_dict)."""
@@ -122,19 +255,13 @@ def rwkv_time_mix(p, x, cfg, *, state=None, x_prev=None,
     h = d // n
     if x_prev is None:
         x_prev = jnp.concatenate([jnp.zeros_like(x[:, :1]), x[:, :-1]], axis=1)
-    r, k, v, g, w = _rwkv_projections(p, x, x_prev, cfg, stats)
+    r, k, v, g, lw = _rwkv_mix(p, x, x_prev, cfg, stats)
     if state is None:
         state = jnp.zeros((b, h, n, n), jnp.float32)
-
-    def step(carry, t):
-        return _wkv_step(carry, t, p["bonus"])
-
-    seq = (r.astype(jnp.float32).transpose(1, 0, 2, 3),
-           k.astype(jnp.float32).transpose(1, 0, 2, 3),
-           v.astype(jnp.float32).transpose(1, 0, 2, 3),
-           w.astype(jnp.float32).reshape(b, s, h, n).transpose(1, 0, 2, 3))
-    state, ys = jax.lax.scan(step, state, seq)         # ys: (S,B,H,N)
-    y = ys.transpose(1, 0, 2, 3)                       # (B,S,H,N)
+    f32 = lambda t: t.astype(jnp.float32)
+    with jax.named_scope(scopes.WKV):
+        y, state = _wkv_chunked(f32(r), f32(k), f32(v), lw, p["bonus"],
+                                state)
     y = layers.group_norm(y, p["ln_x_scale"], p["ln_x_bias"])
     y = y.reshape(b, s, d).astype(x.dtype) * g
     out = layers.dense(p["o"], y, stats=stats, name="o")

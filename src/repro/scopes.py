@@ -24,6 +24,10 @@ device trace's op events; no op changes.  The names:
     the rank-1 statistic mean, and the gather of owner-sharded inverse
     factors (``training/loop.py``, ``sharding/collectives.py``).
 
+Inside the model step, RWKV-6's wkv recurrence runs under ``wkv``
+(``models/ssm.py``).  It is not a stage: its ops keep the ``forward`` or
+backward stage of the pass they run in.
+
 An op belongs to the innermost of these names in its ``op_name``
 (:func:`stage_of`); ops that XLA inserts, such as copies, have no
 ``op_name`` and belong to none.  The host spans of the chunk loop
@@ -50,6 +54,7 @@ OWNER_GATHER = "owner_gather"
 STAGES = (FORWARD, MKOR_STATS, MKOR_SMW, MKOR_PRECONDITION, BACKEND, APPLY,
           GRAD_ALLREDUCE, STAT_ALLREDUCE, OWNER_GATHER)
 BACKWARD = "backward"           # what stage_of calls a transposed forward op
+WKV = "wkv"                     # RWKV-6's recurrence, inside a stage
 
 # the Pallas kernels' names (``pl.pallas_call(name=...)``)
 SMW_KERNEL = "mkor_smw"

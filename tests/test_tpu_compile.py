@@ -5,7 +5,8 @@ them to Mosaic, the chip's kernel compiler, which refuses what the
 interpreter accepts (an unaligned slice, a scalar store into a VMEM
 block, more VMEM than a kernel may hold).  Each test here compiles one
 main-path kernel entry for a described ``v5e:2x2`` chip: nothing runs and
-no chip is needed.
+no chip is needed.  One more compiles RWKV-6's time mixing with its
+gradient at rwkv6-3b's widths, to see the loops the chip's compiler keeps.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
@@ -22,7 +23,9 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from repro import scopes
+from repro.configs import registry
 from repro.kernels import ops
+from repro.models import ssm
 
 D_MODEL, D_FF = 1024, 4096           # bert-large (configs/bert_large.py)
 
@@ -141,3 +144,35 @@ def test_fused_precondition_fallback_compiles_at_rwkv6_slices(
                         kernel=scopes.MATMUL_KERNEL)
     assert len(re.findall(r'custom_call_target="tpu_custom_call"',
                           text)) == 2
+
+
+def test_rwkv_time_mix_gradient_loops_over_chunks_on_v5e(one_chip):
+    """RWKV-6 time mixing and its gradient at rwkv6-3b's widths and the
+    cell's 2 x 512 tokens, compiled for the chip: the wkv runs as one loop
+    over the 512 / WKV_CHUNK chunks forward and one backward, and no
+    buffer holds a state per token."""
+    cfg = registry.get_config("rwkv6-3b")
+    b, s = 2, 512
+    p = jax.eval_shape(lambda: ssm.rwkv_init(jax.random.key(0), cfg,
+                                             dtype=jnp.bfloat16))
+    p = jax.tree.map(lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                                    sharding=one_chip), p)
+    x = jax.ShapeDtypeStruct((b, s, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        y, st = ssm.rwkv_time_mix(p, x, cfg)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(st["wkv"])
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile() \
+        .as_text()
+    conds = re.findall(r" while\(.*?condition=%([\w.\-]+)", text)
+    trips = []
+    for cond in conds:
+        body = re.search(rf"^%{re.escape(cond)} .*?^}}", text,
+                         re.M | re.S).group(0)
+        trips += [int(n) for n in re.findall(r"constant\((\d+)\)", body)]
+    assert len(conds) == 2 and trips == [s // ssm.WKV_CHUNK] * 2, trips
+    heads = cfg.d_model // cfg.rwkv_head_dim
+    state = f"{b},{heads},{cfg.rwkv_head_dim},{cfg.rwkv_head_dim}"
+    assert not re.search(rf"\[{s},{state}\]", text)
